@@ -23,6 +23,7 @@ this module never drags in the model or kernel packages.
 """
 from __future__ import annotations
 
+import functools
 import os
 import time
 from contextlib import contextmanager
@@ -30,10 +31,12 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 # observability plane (decision-free): per-op call counters + opt-in
 # eager timing; one boolean read per public-op call when disabled
 from repro.obs.metrics import METRICS
+from repro.parallel.act import logical_spec, per_shard
 
 ENV_VAR = "REPRO_KERNELS"
 
@@ -151,6 +154,7 @@ def autotuned(op: str, dims: Sequence[int], dtype, *,
     the heuristic ``default`` is then returned **without caching** so a
     later eager call can still tune the bucket.  On CPU/GPU (interpret
     mode — timing is meaningless) the default is returned and cached.
+    When every candidate fails, the last failure is raised.
     """
     be = backend or jax.default_backend()
     key = (op, _bucket(dims) + tuple(exact), jnp.dtype(dtype).name, be)
@@ -162,7 +166,7 @@ def autotuned(op: str, dims: Sequence[int], dtype, *,
     if be == "tpu":
         if make_thunk is None:
             return best               # tracing: usable but not tuned/cached
-        best_t = float("inf")
+        best_t, last_err = float("inf"), None
         for params in candidates:
             try:
                 thunk = make_thunk(params)
@@ -170,10 +174,14 @@ def autotuned(op: str, dims: Sequence[int], dtype, *,
                 t0 = time.perf_counter()
                 thunk()
                 dt = time.perf_counter() - t0
-            except Exception:                             # noqa: BLE001
-                continue                                  # infeasible tile
+            except Exception as e:                        # noqa: BLE001
+                last_err = e                              # infeasible tile
+                continue
             if dt < best_t:
                 best_t, best = dt, dict(params)
+        if best_t == float("inf"):
+            raise RuntimeError(
+                f"autotune: every {op} candidate failed") from last_err
     _AUTOTUNE_CACHE[key] = best
     return best
 
@@ -198,6 +206,30 @@ def _attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
                              softmax_scale=softmax_scale)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention_vjp(q, k, v, causal, window, softmax_scale, block_q,
+                         block_k):
+    from repro.kernels.flash_attention import flash_attention
+    return flash_attention(q, k, v, causal=causal, window=window,
+                           softmax_scale=softmax_scale, block_q=block_q,
+                           block_k=block_k)
+
+
+def _flash_attention_fwd(q, k, v, *static):
+    return _flash_attention_vjp(q, k, v, *static), (q, k, v)
+
+
+def _flash_attention_bwd(causal, window, softmax_scale, block_q, block_k,
+                         res, g):
+    _, vjp = jax.vjp(functools.partial(_attention_ref, causal=causal,
+                                       window=window,
+                                       softmax_scale=softmax_scale), *res)
+    return vjp(g)
+
+
+_flash_attention_vjp.defvjp(_flash_attention_fwd, _flash_attention_bwd)
+
+
 def _attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                       softmax_scale: Optional[float] = None):
     from repro.kernels.flash_attention import flash_attention
@@ -215,13 +247,23 @@ def _attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                     for bq in (128, 256) for bk in (128, 256)],
         default={"block_q": 128, "block_k": 128},
         make_thunk=thunk_for if _concrete(q, k, v) else None)
-    return flash_attention(q, k, v, causal=causal, window=window,
-                           softmax_scale=softmax_scale, **params)
+    # per (batch, head) shard: attention never mixes either
+    qs = logical_spec(q.shape, "batch", None, "heads", None)
+    ks = logical_spec(k.shape, "batch", None, "heads", None)
+    return per_shard(
+        lambda q, k, v: _flash_attention_vjp(
+            q, k, v, causal, window, softmax_scale, params["block_q"],
+            params["block_k"]),
+        (qs, ks, ks), qs)(q, k, v)
 
 
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
               softmax_scale: Optional[float] = None):
-    """q: (b, sq, H, D); k, v: (b, sk, K, D), H = K*G.  Returns (b, sq, H, D)."""
+    """q: (b, sq, H, D); k, v: (b, sk, K, D), H = K*G.  Returns (b, sq, H, D).
+
+    The Pallas kernel is forward-only.  Its ``custom_vjp`` saves q, k, v
+    and takes the backward as the VJP of the chunked ref
+    (``models.attention.chunked_attention``), recomputed from them."""
     if METRICS.enabled:
         return _observed("attention", resolve("attention")[1], (q, k, v),
                          dict(causal=causal, window=window,
@@ -301,6 +343,24 @@ def _ssd_ref(x, dt_raw, A_log, B, C, D, dt_bias, *, chunk: int = 128):
     return ssd_chunked(x, dt, A, B, C, D, chunk=chunk)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _ssd_scan_vjp(x, dt_raw, A_log, B, C, D, dt_bias, chunk, kernel_chunk):
+    from repro.kernels.ssd_scan import ssd_scan
+    return ssd_scan(x, dt_raw, A_log, B, C, D, dt_bias, chunk=kernel_chunk)
+
+
+def _ssd_scan_fwd(*args):
+    return _ssd_scan_vjp(*args), args[:7]
+
+
+def _ssd_scan_bwd(chunk, kernel_chunk, res, g):
+    _, vjp = jax.vjp(functools.partial(_ssd_ref, chunk=chunk), *res)
+    return vjp(g)
+
+
+_ssd_scan_vjp.defvjp(_ssd_scan_fwd, _ssd_scan_bwd)
+
+
 def _ssd_pallas(x, dt_raw, A_log, B, C, D, dt_bias, *, chunk: int = 128):
     from repro.kernels.ssd_scan import ssd_scan
 
@@ -317,12 +377,27 @@ def _ssd_pallas(x, dt_raw, A_log, B, C, D, dt_bias, *, chunk: int = 128):
         candidates=[{"chunk": c} for c in (64, 128, 256)],
         default={"chunk": chunk}, exact=(chunk,),
         make_thunk=thunk_for if _concrete(x, dt_raw, B, C) else None)
-    return ssd_scan(x, dt_raw, A_log, B, C, D, dt_bias, **params)
+    # per (batch, head) shard: the scan never mixes either
+    b, _, h, p = x.shape
+    hs = logical_spec((h,), "heads_inner")
+    bn = logical_spec(B.shape, "batch", None, None)
+    xs = logical_spec(x.shape, "batch", None, "heads_inner", None)
+    return per_shard(
+        lambda *a: _ssd_scan_vjp(*a, chunk, params["chunk"]),
+        (xs, logical_spec(dt_raw.shape, "batch", None, "heads_inner"), hs,
+         bn, bn, hs, hs),
+        (xs, logical_spec((b, h, p, B.shape[-1]), "batch", "heads_inner",
+                          None, None)))(x, dt_raw, A_log, B, C, D, dt_bias)
 
 
 def ssd(x, dt_raw, A_log, B, C, D, dt_bias, *, chunk: int = 128):
     """x: (b,s,h,p); dt_raw pre-softplus (b,s,h); A_log/D/dt_bias (h,);
-    B, C: (b,s,n).  Returns (y (b,s,h,p), final_state (b,h,p,n) fp32)."""
+    B, C: (b,s,n).  Returns (y (b,s,h,p), final_state (b,h,p,n) fp32).
+
+    The Pallas kernel is forward-only.  Its ``custom_vjp`` saves the seven
+    inputs and takes the backward as the VJP of the chunked ref
+    (``models.mamba2.ssd_chunked`` at the caller's ``chunk``), recomputed
+    from them."""
     if METRICS.enabled:
         return _observed("ssd_scan", resolve("ssd_scan")[1],
                          (x, dt_raw, A_log, B, C, D, dt_bias),
@@ -332,7 +407,7 @@ def ssd(x, dt_raw, A_log, B, C, D, dt_bias, *, chunk: int = 128):
 
 
 def _adam_ref(g, m, v, master, *, lr, beta1: float, beta2: float,
-              eps: float, wd: float, c1, c2):
+              eps: float, wd: float, c1, c2, spec=None):
     g = g.astype(jnp.float32)
     m = beta1 * m + (1.0 - beta1) * g
     v = beta2 * v + (1.0 - beta2) * jnp.square(g)
@@ -343,7 +418,7 @@ def _adam_ref(g, m, v, master, *, lr, beta1: float, beta2: float,
 
 
 def _adam_pallas(g, m, v, master, *, lr, beta1: float, beta2: float,
-                 eps: float, wd: float, c1, c2):
+                 eps: float, wd: float, c1, c2, spec=None):
     from repro.kernels.adam_update import adam_update_fused
 
     def thunk_for(params):
@@ -359,24 +434,31 @@ def _adam_pallas(g, m, v, master, *, lr, beta1: float, beta2: float,
         default={"block": 64 * 1024},
         make_thunk=thunk_for if _concrete(g, m, v, master, lr, c1, c2)
         else None)
-    m2, v2, mp2, _ = adam_update_fused(g, m, v, master, lr=lr, beta1=beta1,
-                                       beta2=beta2, eps=eps, wd=wd,
-                                       c1=c1, c2=c2, **params)
-    return m2, v2, mp2
+
+    def update(g, m, v, master, lr, c1, c2):
+        return adam_update_fused(g, m, v, master, lr=lr, beta1=beta1,
+                                 beta2=beta2, eps=eps, wd=wd, c1=c1, c2=c2,
+                                 **params)[:3]
+    # elementwise: any shard of the leaf updates on its own
+    spec = P() if spec is None else spec
+    return per_shard(update, (spec,) * 4 + (P(),) * 3, (spec,) * 3)(
+        g, m, v, master, lr, c1, c2)
 
 
 def adam_update_leaf(g, m, v, master, *, lr, beta1: float, beta2: float,
-                     eps: float, wd: float, c1, c2):
+                     eps: float, wd: float, c1, c2, spec=None):
     """One fused Adam step on one (flattened) parameter leaf.  All fp32;
-    lr/c1/c2 may be traced.  Returns (m', v', master')."""
+    lr/c1/c2 may be traced.  ``spec`` is the leaf's PartitionSpec on the
+    active mesh; the Pallas kernel runs once per shard of it.  Returns
+    (m', v', master')."""
     if METRICS.enabled:
         return _observed("adam_update", resolve("adam_update")[1],
                          (g, m, v, master),
                          dict(lr=lr, beta1=beta1, beta2=beta2, eps=eps,
-                              wd=wd, c1=c1, c2=c2))
+                              wd=wd, c1=c1, c2=c2, spec=spec))
     return resolve("adam_update")[1](g, m, v, master, lr=lr, beta1=beta1,
                                      beta2=beta2, eps=eps, wd=wd,
-                                     c1=c1, c2=c2)
+                                     c1=c1, c2=c2, spec=spec)
 
 
 register("attention", pallas=_attention_pallas, ref=_attention_ref)

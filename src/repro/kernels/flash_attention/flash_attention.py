@@ -6,6 +6,12 @@ state (m, l, acc) in VMEM scratch across grid steps — the canonical TPU
 revisiting-output pattern.  Blocks fully outside the causal/window band are
 skipped with ``pl.when`` so the MXU only sees useful work.  Block shapes are
 128-aligned for the MXU.
+
+The kernel runs head-major: the wrapper transposes (b, s, H, D) to
+(b, H, s, D) so every block is a (seq-block, D) tile.  The TPU compiler
+only accepts blocks whose last two dims are (8, 128)-divisible or whole,
+and a one-head slice of the sequence-major layout puts a size-1 block on
+the head axis in the second-to-last position.
 """
 from __future__ import annotations
 
@@ -44,9 +50,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :]                      # (bq, D)
-        k = k_ref[0, :, 0, :]                      # (bk, D)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0]                            # (bq, D)
+        k = k_ref[0, 0]                            # (bk, D)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (bq, bk)
@@ -72,7 +78,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(j == nk - 1)
     def _finish():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -89,14 +95,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         interpret = jax.default_backend() == "cpu"
     bq = min(block_q, max(sq, 8))
     bk = min(block_k, max(sk, 8))
-    # pad sequences to block multiples
+    # head-major, sequences padded to block multiples
     sq_p = -(-sq // bq) * bq
     sk_p = -(-sk // bk) * bk
-    if sq_p != sq:
-        q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
-    if sk_p != sk:
-        k = jnp.pad(k, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
+    q = jnp.pad(q.swapaxes(1, 2), ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
+    k = jnp.pad(k.swapaxes(1, 2), ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
+    v = jnp.pad(v.swapaxes(1, 2), ((0, 0), (0, 0), (0, sk_p - sk), (0, 0)))
     grid = (b, H, sq_p // bq, sk_p // bk)
 
     out = pl.pallas_call(
@@ -104,13 +108,13 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                           bq=bq, bk=bk, sk=sk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda ib, ih, iq, ik: (ib, ik, ih // G, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda ib, ih, iq, ik: (ib, ik, ih // G, 0)),
+            pl.BlockSpec((1, 1, bq, D), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda ib, ih, iq, ik: (ib, ih // G, ik, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda ib, ih, iq, ik: (ib, ih // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, D),
-                               lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, H, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, D),
+                               lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, H, sq_p, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -118,4 +122,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :sq]
+    return out[:, :, :sq].swapaxes(1, 2)

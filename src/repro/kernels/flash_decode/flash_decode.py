@@ -56,28 +56,33 @@ def _combine(acc, m, l, out_dtype):
 
 
 # -------------------------------------------------------------- GQA ------
+# The cache keeps its (b, S, K, D) layout: a block holds every KV head of
+# bs cache rows, (bs, K, D), whose last two dims are whole, and the kernel
+# walks the heads.  A one-head block would put a size-1 block on the head
+# axis in the second-to-last position, which the TPU compiler refuses, and
+# transposing the cache to head-major would cost a full cache pass per
+# token.  m and l carry a trailing unit dim for the same tiling rule.
 
 def _gqa_kernel(q_ref, k_ref, v_ref, valid_ref, acc_ref, m_ref, l_ref, *,
-                scale: float):
-    q = q_ref[0, 0]                                 # (G, D)
-    k = k_ref[0, :, 0, :]                           # (bs, D)
-    v = v_ref[0, :, 0, :]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale  # (G, bs)
-    ok = valid_ref[...] > 0                         # (1, bs)
-    s = jnp.where(ok, s, NEG_INF)
-    m = jnp.max(s, axis=1)                          # (G,)
-    # a fully-masked block has m == NEG_INF and exp(s - m) == 1 garbage;
-    # zeroing p keeps its (acc, l) partial inert in the combine
-    p = jnp.where(ok, jnp.exp(s - m[:, None]), 0.0)
-    l = jnp.sum(p, axis=1)
-    acc = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # (G, D)
-    acc_ref[...] = acc.reshape(acc_ref.shape)
-    m_ref[...] = m.reshape(m_ref.shape)
-    l_ref[...] = l.reshape(l_ref.shape)
+                scale: float, n_kv: int):
+    ok = valid_ref[0] > 0                           # (1, bs)
+    for h in range(n_kv):
+        q = q_ref[0, h]                             # (G, D)
+        k = k_ref[0, :, h, :]                       # (bs, D)
+        v = v_ref[0, :, h, :]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (G, bs)
+        s = jnp.where(ok, s, NEG_INF)
+        m = jnp.max(s, axis=1, keepdims=True)       # (G, 1)
+        # a fully-masked block has m == NEG_INF and exp(s - m) == 1 garbage;
+        # zeroing p keeps its (acc, l) partial inert in the combine
+        p = jnp.where(ok, jnp.exp(s - m), 0.0)
+        acc_ref[0, 0, h] = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)      # (G, D)
+        m_ref[0, 0, h] = m
+        l_ref[0, 0, h] = jnp.sum(p, axis=1, keepdims=True)
 
 
 def flash_decode_gqa(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -102,31 +107,29 @@ def flash_decode_gqa(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         v_cache = jnp.pad(v_cache, pad + ((0, 0), (0, 0)))
         vmask = jnp.pad(vmask, pad)                  # padding is masked out
     ns = Sp // bs
-    grid = (b, K, ns)
 
     acc, m, l = pl.pallas_call(
-        functools.partial(_gqa_kernel, scale=scale),
-        grid=grid,
+        functools.partial(_gqa_kernel, scale=scale, n_kv=K),
+        grid=(b, ns),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda ib, ik, js: (ib, ik, 0, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda ib, ik, js: (ib, js, ik, 0)),
-            pl.BlockSpec((1, bs, 1, D), lambda ib, ik, js: (ib, js, ik, 0)),
-            pl.BlockSpec((1, bs), lambda ib, ik, js: (ib, js)),
+            pl.BlockSpec((1, K, G, D), lambda ib, js: (ib, 0, 0, 0)),
+            pl.BlockSpec((1, bs, K, D), lambda ib, js: (ib, js, 0, 0)),
+            pl.BlockSpec((1, bs, K, D), lambda ib, js: (ib, js, 0, 0)),
+            pl.BlockSpec((1, 1, bs), lambda ib, js: (ib, 0, js)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, G, D),
-                         lambda ib, ik, js: (ib, js, ik, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda ib, ik, js: (ib, js, ik, 0)),
-            pl.BlockSpec((1, 1, 1, G), lambda ib, ik, js: (ib, js, ik, 0)),
+            pl.BlockSpec((1, 1, K, G, D), lambda ib, js: (ib, js, 0, 0, 0)),
+            pl.BlockSpec((1, 1, K, G, 1), lambda ib, js: (ib, js, 0, 0, 0)),
+            pl.BlockSpec((1, 1, K, G, 1), lambda ib, js: (ib, js, 0, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, ns, K, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((b, ns, K, G), jnp.float32),
-            jax.ShapeDtypeStruct((b, ns, K, G), jnp.float32),
+            jax.ShapeDtypeStruct((b, ns, K, G, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, ns, K, G, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q.reshape(b, K, G, D), k_cache, v_cache, vmask)
-    out = _combine(acc, m, l, v_cache.dtype)         # (b, K, G, D)
+    )(q.reshape(b, K, G, D), k_cache, v_cache, vmask[:, None, :])
+    out = _combine(acc, m[..., 0], l[..., 0], v_cache.dtype)  # (b, K, G, D)
     return out.reshape(b, 1, H, D)
 
 
@@ -142,17 +145,15 @@ def _mla_kernel(ql_ref, qr_ref, c_ref, kr_ref, valid_ref, acc_ref, m_ref,
                              preferred_element_type=jnp.float32)
          + jax.lax.dot_general(qr, kr, (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)) / denom
-    ok = valid_ref[...] > 0                          # (1, bs)
+    ok = valid_ref[0] > 0                            # (1, bs)
     s = jnp.where(ok, s, NEG_INF)                    # (H, bs)
-    m = jnp.max(s, axis=1)
-    p = jnp.where(ok, jnp.exp(s - m[:, None]), 0.0)
-    l = jnp.sum(p, axis=1)
-    acc = jax.lax.dot_general(
+    m = jnp.max(s, axis=1, keepdims=True)            # (H, 1)
+    p = jnp.where(ok, jnp.exp(s - m), 0.0)
+    acc_ref[0, 0] = jax.lax.dot_general(
         p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)          # (H, r)
-    acc_ref[...] = acc.reshape(acc_ref.shape)
-    m_ref[...] = m.reshape(m_ref.shape)
-    l_ref[...] = l.reshape(l_ref.shape)
+    m_ref[0, 0] = m
+    l_ref[0, 0] = jnp.sum(p, axis=1, keepdims=True)
 
 
 def flash_decode_mla(q_lat: jax.Array, q_rope: jax.Array, c_kv: jax.Array,
@@ -174,28 +175,27 @@ def flash_decode_mla(q_lat: jax.Array, q_rope: jax.Array, c_kv: jax.Array,
         k_rope = jnp.pad(k_rope, pad + ((0, 0),))
         vmask = jnp.pad(vmask, pad)
     ns = Sp // bs
-    grid = (b, ns)
 
     acc, m, l = pl.pallas_call(
         functools.partial(_mla_kernel, denom=denom),
-        grid=grid,
+        grid=(b, ns),
         in_specs=[
             pl.BlockSpec((1, H, r), lambda ib, js: (ib, 0, 0)),
             pl.BlockSpec((1, H, dr), lambda ib, js: (ib, 0, 0)),
             pl.BlockSpec((1, bs, r), lambda ib, js: (ib, js, 0)),
             pl.BlockSpec((1, bs, dr), lambda ib, js: (ib, js, 0)),
-            pl.BlockSpec((1, bs), lambda ib, js: (ib, js)),
+            pl.BlockSpec((1, 1, bs), lambda ib, js: (ib, 0, js)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, H, r), lambda ib, js: (ib, js, 0, 0)),
-            pl.BlockSpec((1, 1, H), lambda ib, js: (ib, js, 0)),
-            pl.BlockSpec((1, 1, H), lambda ib, js: (ib, js, 0)),
+            pl.BlockSpec((1, 1, H, 1), lambda ib, js: (ib, js, 0, 0)),
+            pl.BlockSpec((1, 1, H, 1), lambda ib, js: (ib, js, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, ns, H, r), jnp.float32),
-            jax.ShapeDtypeStruct((b, ns, H), jnp.float32),
-            jax.ShapeDtypeStruct((b, ns, H), jnp.float32),
+            jax.ShapeDtypeStruct((b, ns, H, 1), jnp.float32),
+            jax.ShapeDtypeStruct((b, ns, H, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q_lat, q_rope, c_kv, k_rope, vmask)
-    return _combine(acc, m, l, c_kv.dtype)           # (b, H, r)
+    )(q_lat, q_rope, c_kv, k_rope, vmask[:, None, :])
+    return _combine(acc, m[..., 0], l[..., 0], c_kv.dtype)   # (b, H, r)

@@ -1,5 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# a CPU emulation of the mesh: never take an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run (deliverable e).
 

@@ -9,19 +9,12 @@ from __future__ import annotations
 import jax
 
 
-def _mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer releases — pass explicit
-    Auto axes when available, fall back to the bare call (same semantics:
-    Auto is the default) otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:          # make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+def _mesh(shape, axes, devices=None):
+    """Auto axes: GSPMD propagates shardings from the explicit constraints
+    (``jax.make_mesh`` defaults to Explicit axes)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,6 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     return _mesh(shape, axes)
 
 
-def make_plan_mesh(d: int, t: int):
-    """Mesh for a MARP plan (d data x t model shards) on real local devices."""
-    return _mesh((d, t), ("data", "model"))
+def make_plan_mesh(d: int, t: int, devices=None):
+    """Mesh for a MARP plan (d data x t model shards) on ``devices``
+    (default: all local devices)."""
+    return _mesh((d, t), ("data", "model"), devices)

@@ -26,6 +26,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import get_arch, smoke_config
+from repro.core import calibration, memtrace
+from repro.core.devices import DEVICE_TYPES
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
 from repro.serve import (ContinuousBatcher, DisaggregatedBatcher,
                          ServeRequest, prefill, serve_step)
@@ -64,6 +67,7 @@ def main(argv=None):
                     help="with --continuous: split prefill front-end from"
                          " the decode loop (DisaggregatedBatcher)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_arch(args.arch)
     key = jax.random.PRNGKey(0)
@@ -121,22 +125,19 @@ def main(argv=None):
           f" {args.batch}x{args.prompt_len} in {dt_prefill:.3f}s"
           f" ({prefill_tok_s:.1f} tok/s), decode {args.gen - 1} steps in"
           f" {dt_decode:.3f}s ({decode_tok_s:.1f} tok/s)")
-    try:
-        from repro.core import calibration, memtrace
-        dt_name = memtrace.device_type_for(jax.devices()[0].device_kind)
-        if dt_name != memtrace.ANY_DEVICE:
-            from repro.core.devices import DEVICE_TYPES
-            eff = calibration.measured_decode_eff(
-                decode_tok_s, cfg, args.batch, cache_len, 1, 1,
-                DEVICE_TYPES[dt_name])
-            print(f"decode-bandwidth efficiency {eff:.3f} of {dt_name}"
-                  f" peak (calibration.enable_decode table entry)")
-            pf_eff = calibration.measured_prefill_eff(
-                prefill_tok_s, cfg, 1, DEVICE_TYPES[dt_name])
-            print(f"prefill MFU {pf_eff:.3f} of {dt_name} peak"
-                  f" (prefill-pool rate model input)")
-    except Exception:  # noqa: BLE001 — telemetry is best-effort
-        pass
+    dt_name = memtrace.device_type_for(jax.devices()[0].device_kind)
+    if dt_name == memtrace.ANY_DEVICE:
+        print(f"device kind {jax.devices()[0].device_kind!r} is not in the"
+              f" catalog: no calibration entry derived")
+    else:
+        dev = DEVICE_TYPES[dt_name]
+        eff = calibration.measured_decode_eff(
+            decode_tok_s, cfg, args.batch, cache_len, 1, 1, dev)
+        print(f"decode-bandwidth efficiency {eff:.3f} of {dt_name}"
+              f" peak (calibration.enable_decode table entry)")
+        pf_eff = calibration.measured_prefill_eff(prefill_tok_s, cfg, 1, dev)
+        print(f"prefill MFU {pf_eff:.3f} of {dt_name} peak"
+              f" (prefill-pool rate model input)")
     print("sample:", toks[0, :12].tolist())
     return toks
 

@@ -14,6 +14,7 @@ from repro.core.orchestrator import (Orchestrator, make_cluster,
                                      PAPER_REAL_CLUSTER, PAPER_SIM_CLUSTER,
                                      TPU_FLEET)
 from repro.core.serverless import submit
+from repro.launch.compile_cache import use_compile_cache
 
 CLUSTERS = {"paper-real": PAPER_REAL_CLUSTER, "paper-sim": PAPER_SIM_CLUSTER,
             "tpu-fleet": TPU_FLEET}
@@ -28,6 +29,7 @@ def main(argv=None):
     ap.add_argument("--cluster", choices=sorted(CLUSTERS), default="paper-sim")
     ap.add_argument("--mode", choices=["exact", "paper"], default="exact")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     orch = Orchestrator(make_cluster(CLUSTERS[args.cluster]))
     print(f"cluster '{args.cluster}': "

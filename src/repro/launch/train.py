@@ -18,8 +18,8 @@ from repro.configs.registry import get_arch, smoke_config
 from repro.core import memory_model as mm
 from repro.core import memtrace
 from repro.data import SyntheticTokens
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_plan_mesh
-from repro.parallel import sharding as sh
 from repro.train import build_train_step, make_train_state, state_specs
 from repro import ckpt as ckpt_mod
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -30,23 +30,60 @@ def record_compile_telemetry(step_jit, state, batch, cfg, tc, d: int,
     """AOT-compile the jitted step and feed its XLA memory accounting into
     the memory feedback plane (``core.memtrace``) — the live-compile
     telemetry source.  Returns the compiled executable so the caller can
-    drive the loop with it (one compile, not two); falls back to the
-    jitted function on any failure (telemetry must never kill training)."""
-    try:
-        compiled = step_jit.lower(state, batch).compile()
-        observed = mm.xla_peak_bytes(compiled.memory_analysis())
-        pred = mm.exact_peak_bytes(cfg, tc.global_batch, tc.seq_len, d, t,
-                                   zero=tc.zero, microbatch=tc.microbatch)
-        dev_type = memtrace.device_type_for(jax.devices()[0].device_kind)
-        memtrace.record(cfg.family, tc.zero, dev_type, pred, observed,
-                        source="xla")
-        print(f"memtrace: observed peak {observed / 2**30:.2f} GiB vs"
-              f" predicted {pred / 2**30:.2f} GiB"
-              f" ({dev_type}, zero={tc.zero})", flush=True)
+    drive the loop with it (one compile, not two).  A failed compile fails
+    the run; a backend without ``memory_analysis`` is reported."""
+    compiled = step_jit.lower(state, batch).compile()
+    ma = compiled.memory_analysis()
+    if ma is None:
+        print("memtrace: this backend reports no memory_analysis",
+              flush=True)
         return compiled
-    except Exception as e:  # noqa: BLE001 — telemetry is best-effort
-        print(f"memtrace: compile telemetry unavailable ({e})", flush=True)
-        return step_jit
+    observed = mm.xla_peak_bytes(ma)
+    pred = mm.exact_peak_bytes(cfg, tc.global_batch, tc.seq_len, d, t,
+                               zero=tc.zero, microbatch=tc.microbatch)
+    dev_type = memtrace.device_type_for(jax.devices()[0].device_kind)
+    memtrace.record(cfg.family, tc.zero, dev_type, pred, observed,
+                    source="xla")
+    print(f"memtrace: observed peak {observed / 2**30:.2f} GiB vs"
+          f" predicted {pred / 2**30:.2f} GiB"
+          f" ({dev_type}, zero={tc.zero})", flush=True)
+    return compiled
+
+
+def run(cfg, tc: TrainConfig, mesh, *, log_every: int = 10):
+    """Train ``tc.steps`` steps of ``cfg`` on ``mesh`` from the seed's
+    weights and data.  Returns (losses, final state)."""
+    d, t = mesh.shape["data"], mesh.shape["model"]
+    batch, seq = tc.global_batch, tc.seq_len
+    state = make_train_state(cfg, tc, jax.random.PRNGKey(tc.seed))
+    sspec = state_specs(cfg, tc, mesh, state)
+    s_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), sspec,
+                        is_leaf=lambda x: isinstance(x, P))
+    state = jax.device_put(state, s_sh)
+    step_jit, _ = build_train_step(cfg, tc, mesh, batch, seq, jit=True)
+
+    it = iter(SyntheticTokens(cfg, batch, seq, seed=tc.seed))
+
+    def prep(raw):
+        return {k: jnp.asarray(v) for k, v in raw.items()
+                if k in ("tokens", "labels", "modal_embeds")}
+
+    # one AOT compile: drives the loop below *and* feeds observed peak
+    # memory into the feedback plane (batch shapes are static, so the
+    # compiled executable serves every step)
+    first = prep(next(it))
+    step_fn = record_compile_telemetry(step_jit, state, first, cfg, tc, d, t)
+    losses = []
+    t0 = time.time()
+    for i in range(tc.steps):
+        state, metrics = step_fn(state, first if i == 0 else prep(next(it)))
+        losses.append(float(metrics["loss"]))
+        if i % log_every == 0 or i == tc.steps - 1:
+            dt = time.time() - t0
+            print(f"step {i:5d} loss {losses[-1]:.4f} "
+                  f"gnorm {float(metrics['grad_norm']):.3f} "
+                  f"({dt:.1f}s)", flush=True)
+    return losses, state
 
 
 def main(argv=None):
@@ -63,6 +100,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_arch(args.arch)
     tc = TrainConfig(global_batch=args.batch, seq_len=args.seq,
@@ -73,49 +111,18 @@ def main(argv=None):
     # serverless mesh sizing: all local devices, data-parallel by default
     n_dev = jax.device_count()
     d = min(n_dev, args.batch)
-    t = n_dev // d
-    mesh = make_plan_mesh(d, max(t, 1))
+    t = max(n_dev // d, 1)
+    mesh = make_plan_mesh(d, t)
     print(f"arch={cfg.name} params on mesh d={d} t={t} "
           f"(devices={n_dev})", flush=True)
-
-    state = make_train_state(cfg, tc, jax.random.PRNGKey(tc.seed))
-    sspec = state_specs(cfg, tc, mesh, state)
-    s_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), sspec,
-                        is_leaf=lambda x: isinstance(x, P))
-    state = jax.device_put(state, s_sh)
-    step_jit, n_micro = build_train_step(cfg, tc, mesh, args.batch, args.seq,
-                                         jit=True)
-
-    data = SyntheticTokens(cfg, args.batch, args.seq, seed=tc.seed)
-    it = iter(data)
-
-    def prep(raw):
-        return {k: jnp.asarray(v) for k, v in raw.items()
-                if k in ("tokens", "labels", "modal_embeds")}
-
-    # one AOT compile: drives the loop below *and* feeds observed peak
-    # memory into the feedback plane (batch shapes are static, so the
-    # compiled executable serves every step)
-    first = prep(next(it))
-    step_fn = record_compile_telemetry(step_jit, state, first, cfg, tc,
-                                       d, max(t, 1))
-    losses = []
-    t0 = time.time()
-    for i in range(args.steps):
-        batch = first if i == 0 else prep(next(it))
-        state, metrics = step_fn(state, batch)
-        losses.append(float(metrics["loss"]))
-        if i % args.log_every == 0 or i == args.steps - 1:
-            dt = time.time() - t0
-            print(f"step {i:5d} loss {losses[-1]:.4f} "
-                  f"gnorm {float(metrics['grad_norm']):.3f} "
-                  f"({dt:.1f}s)", flush=True)
+    losses, state = run(cfg, tc, mesh, log_every=args.log_every)
     if args.ckpt_dir:
         ckpt_mod.save(args.ckpt_dir, args.steps, state["params"])
         print(f"checkpoint saved to {args.ckpt_dir}")
-    print(f"first-10-mean {np.mean(losses[:10]):.4f} "
-          f"last-10-mean {np.mean(losses[-10:]):.4f}")
-    assert np.mean(losses[-10:]) < np.mean(losses[:10]), "loss did not fall"
+    first10, last10 = np.mean(losses[:10]), np.mean(losses[-10:])
+    print(f"first-10-mean {first10:.4f} last-10-mean {last10:.4f}")
+    if not last10 < first10:
+        raise RuntimeError(f"loss did not fall: {first10} -> {last10}")
     return losses
 
 

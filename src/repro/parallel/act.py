@@ -59,13 +59,16 @@ def activation_sharding(mesh: Mesh, cfg):
         _CTX.ctx = prev
 
 
-def constrain(x: jax.Array, *dims) -> jax.Array:
+def logical_spec(shape, *dims) -> P:
+    """The PartitionSpec the active context gives an array of ``shape``
+    with logical ``dims``; axes that do not divide their dim are dropped.
+    Fully replicated when no context is active."""
     ctx = getattr(_CTX, "ctx", None)
     if ctx is None:
-        return x
+        return P()
     mesh, resolved = ctx
     entries = []
-    for dim_size, name in zip(x.shape, dims):
+    for dim_size, name in zip(shape, dims):
         ax = resolved.get(name)
         if ax is not None:
             n = 1
@@ -76,5 +79,27 @@ def constrain(x: jax.Array, *dims) -> jax.Array:
         if isinstance(ax, tuple) and len(ax) == 1:
             ax = ax[0]
         entries.append(ax)
-    spec = P(*entries)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return P(*entries)
+
+
+def constrain(x: jax.Array, *dims) -> jax.Array:
+    ctx = getattr(_CTX, "ctx", None)
+    if ctx is None:
+        return x
+    return jax.lax.with_sharding_constraint(
+        x, NamedSharding(ctx[0], logical_spec(x.shape, *dims)))
+
+
+def per_shard(fn, in_specs, out_specs):
+    """``fn`` run once per shard of the active mesh (``jax.shard_map``
+    with PartitionSpec pytrees ``in_specs``/``out_specs``).
+
+    For kernels the SPMD partitioner cannot split: a Pallas call is one
+    opaque custom call.  ``fn`` must give the right answer on each shard
+    alone.  With no context active, or a one-device mesh, ``fn`` itself
+    is returned."""
+    ctx = getattr(_CTX, "ctx", None)
+    if ctx is None or ctx[0].size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=ctx[0], in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -39,10 +39,12 @@ def init_opt_state(params: Any) -> Dict[str, Any]:
 
 
 def adam_update(tc: TrainConfig, params: Any, opt: Dict[str, Any],
-                grads: Any, step: jax.Array
+                grads: Any, step: jax.Array, specs: Any = None
                 ) -> Tuple[Any, Dict[str, Any], jax.Array]:
-    """One Adam step.  grads are fp32, already mean-reduced.  Returns
-    (new bf16 params, new opt state, global grad norm)."""
+    """One Adam step.  grads are fp32, already mean-reduced.  ``specs`` is
+    the PartitionSpec tree of the grads and optimizer state on the active
+    mesh, if any.  Returns (new bf16 params, new opt state, global grad
+    norm)."""
     lr = lr_at(tc, step)
     t = step.astype(jnp.float32) + 1.0
     c1 = 1.0 - tc.beta1 ** t
@@ -55,13 +57,15 @@ def adam_update(tc: TrainConfig, params: Any, opt: Dict[str, Any],
     flat_m = treedef.flatten_up_to(opt["m"])
     flat_v = treedef.flatten_up_to(opt["v"])
     flat_p = treedef.flatten_up_to(opt["master"])
+    flat_s = ([None] * len(flat_g) if specs is None
+              else treedef.flatten_up_to(specs))
     new_m, new_v, new_master = [], [], []
-    for g, m, v, mp in zip(flat_g, flat_m, flat_v, flat_p):
+    for g, m, v, mp, spec in zip(flat_g, flat_m, flat_v, flat_p, flat_s):
         # decoupled weight decay on matrices only (ndim >= 2)
         wd = tc.weight_decay if mp.ndim >= 2 else 0.0
         m2, v2, p2 = dispatch.adam_update_leaf(
             g, m, v, mp, lr=lr, beta1=tc.beta1, beta2=tc.beta2,
-            eps=tc.eps, wd=wd, c1=c1, c2=c2)
+            eps=tc.eps, wd=wd, c1=c1, c2=c2, spec=spec)
         new_m.append(m2)
         new_v.append(v2)
         new_master.append(p2)

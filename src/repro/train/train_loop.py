@@ -118,7 +118,12 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh,
         (g_sum, loss_sum), _ = jax.lax.scan(accum, (g0, 0.0), micros)
         grads = jax.tree.map(lambda g: g / n_micro, g_sum)
         new_params, new_opt, gnorm = adam_update(
-            tc, params, state["opt"], grads, state["step"])
+            tc, params, state["opt"], grads, state["step"], opt_spec)
+        # hand the params back in the layout they came in: left free, the
+        # compiler shards them like the ZeRO master copy they are cast
+        # from, and a compiled step then refuses its own output next step
+        p_spec = sh.param_specs(cfg, params, mesh, zero_data=tc.zero >= 3)
+        new_params = jax.tree.map(constrain, new_params, p_spec)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         metrics = {"loss": loss_sum / n_micro, "grad_norm": gnorm}

@@ -14,7 +14,7 @@ import random
 
 import pytest
 
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.cluster import traces
 from repro.cluster.schedulers import FrenzyScheduler

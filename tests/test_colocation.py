@@ -19,7 +19,7 @@ from repro.cluster.simulator import simulate
 from repro.core.has import ClusterPool, Grant, Node
 from repro.core.marp import ResourcePlan
 
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 
 GB = 1024 ** 3
 
@@ -124,7 +124,6 @@ def _drive(ops):
     pool._debug_check_slices()
 
 
-@pytest.mark.skipif(not HAVE_HYPOTHESIS, reason="hypothesis not installed")
 @settings(max_examples=60, deadline=None)
 @given(st.lists(st.integers(min_value=0, max_value=2 ** 63 - 1),
                 max_size=60))

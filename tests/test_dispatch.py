@@ -256,6 +256,71 @@ def test_dispatch_pallas_attention_matches_ref(b, sq, sk, H, K, D, causal,
                                atol=tol, rtol=tol)
 
 
+# ------------------- custom_vjp: Pallas forward, chunked-ref backward ---
+
+@pytest.mark.parametrize("b,s,H,K,D,window", [
+    (2, 128, 4, 2, 64, 0),                     # GQA causal
+    (1, 128, 4, 4, 32, 48),                    # MHA + sliding window
+    (1, 256, 8, 2, 64, 0),                     # GQA, two 128-row blocks
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_dispatch_pallas_attention_grad_matches_ref(b, s, H, K, D, window,
+                                                    dtype):
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(ks[0], (b, s, H, D), dtype)
+    k = jax.random.normal(ks[1], (b, s, K, D), dtype)
+    v = jax.random.normal(ks[2], (b, s, K, D), dtype)
+    w = jax.random.normal(ks[3], (b, s, H, D), jnp.float32)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v, causal=True, window=window).astype(jnp.float32)
+            * w)
+    with dispatch.force("pallas"):
+        got = jax.value_and_grad(loss(dispatch.attention),
+                                 argnums=(0, 1, 2))(q, k, v)
+    want = jax.value_and_grad(loss(attention_ref), argnums=(0, 1, 2))(q, k, v)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for a, b_ in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        scale = max(float(jnp.max(jnp.abs(b_.astype(jnp.float32)))), 1.0)
+        np.testing.assert_allclose(np.asarray(a, np.float32) / scale,
+                                   np.asarray(b_, np.float32) / scale,
+                                   atol=tol, rtol=tol)
+
+
+def test_dispatch_pallas_ssd_grad_matches_ref():
+    from repro.kernels.ssd_scan import ssd_ref
+    ks = jax.random.split(jax.random.PRNGKey(12), 8)
+    b, s, h, p, n = 1, 128, 2, 32, 16
+    args = (jax.random.normal(ks[0], (b, s, h, p)),
+            jax.random.normal(ks[1], (b, s, h)) * 0.5,
+            jax.random.normal(ks[2], (h,)) * 0.3,
+            jax.random.normal(ks[3], (b, s, n)),
+            jax.random.normal(ks[4], (b, s, n)),
+            jax.random.normal(ks[5], (h,)),
+            jnp.full((h,), 0.1, jnp.float32))
+    wy = jax.random.normal(ks[6], (b, s, h, p))
+    ws = jax.random.normal(ks[7], (b, h, p, n))
+
+    def loss(y, st):
+        return jnp.sum(y * wy) + jnp.sum(st * ws)
+
+    def pallas_loss(*a):
+        return loss(*dispatch.ssd(*a, chunk=32))
+
+    def ref_loss(x, dt_raw, A_log, B, C, D, dt_bias):
+        dt = jax.nn.softplus(dt_raw + dt_bias)
+        return loss(*ssd_ref(x, dt, -jnp.exp(A_log), B, C, D))
+    with dispatch.force("pallas"):
+        got = jax.value_and_grad(pallas_loss, argnums=tuple(range(7)))(*args)
+    want = jax.value_and_grad(ref_loss, argnums=tuple(range(7)))(*args)
+    for a, b_ in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        scale = max(float(jnp.max(jnp.abs(b_))), 1.0)
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(b_) / scale,
+                                   atol=2e-3, rtol=2e-3)
+
+
 def test_dispatch_pallas_ssd_and_adam_match_ref():
     key = jax.random.PRNGKey(6)
     ks = jax.random.split(key, 5)

@@ -4,7 +4,7 @@ import copy
 
 import jax.numpy as jnp
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.registry import ARCHS
 from repro.core import memory_model as mm

@@ -13,7 +13,7 @@ import random
 
 import pytest
 
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.ckpt.checkpoint import (checkpoint_seconds, migration_seconds,
                                    state_bytes)

@@ -16,7 +16,7 @@ from repro.core.lifecycle import (ClusterEvent, HASAdmission, Job,
 from repro.core.marp import ResourcePlan
 from repro.core.orchestrator import Orchestrator, make_cluster, \
     PAPER_SIM_CLUSTER
-from tests._hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 
 GB = 1024 ** 3
 
@@ -204,7 +204,6 @@ def test_rejoining_node_goes_to_back_of_fifo_tiebreak():
     assert pool.find_placements(plan) == (("b", 4),)
 
 
-@pytest.mark.skipif(not HAVE_HYPOTHESIS, reason="hypothesis not installed")
 @settings(max_examples=60, deadline=None)
 @given(st.lists(st.tuples(st.integers(0, 3), st.integers(0, 7),
                           st.integers(1, 8)), min_size=1, max_size=120))

@@ -1,6 +1,6 @@
 """MARP plan enumeration + HAS Algorithm 1, incl. hypothesis properties."""
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import TrainConfig
 from repro.configs.registry import ARCHS

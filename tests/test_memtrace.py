@@ -4,7 +4,7 @@ import copy
 
 import pytest
 
-from tests._hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.cluster.schedulers import FrenzyScheduler
 from repro.cluster.simulator import simulate

@@ -37,6 +37,25 @@ def test_submit_driver():
     assert all(r.started for r in results)
 
 
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """The entry points' compile cache: a fixed directory in the checkout,
+    unless JAX_COMPILATION_CACHE_DIR places it (JAX then reads it itself
+    and the helper sets nothing)."""
+    from repro.launch import compile_cache as cc
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv(cc.ENV_VAR, str(tmp_path))
+        assert cc.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv(cc.ENV_VAR)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.use_compile_cache() == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == cc.DEFAULT_DIR
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
 def test_data_pipeline_shapes_and_determinism():
     cfg = smoke_config("llava-next-34b")
     d1 = iter(SyntheticTokens(cfg, 4, 32 + cfg.num_modal_tokens, seed=7))

@@ -1,0 +1,180 @@
+"""Compile the Pallas kernels for a described TPU v5e chip at real widths.
+
+Interpret mode accepts block shapes that the chip's compiler refuses, so
+each test lowers and compiles a kernel (or its gradient through the
+dispatch layer's ``custom_vjp``, taken with ``value_and_grad`` as the
+train step does: ``grad`` alone needs no forward output, and the kernel
+would be dead code) for one chip of a described ``v5e:2x2``
+topology and checks that the kernel is in the program
+(``tpu_custom_call``).  Nothing runs; no chip is needed.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import dispatch
+from repro.kernels.adam_update import adam_update_fused
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_decode import flash_decode_gqa, flash_decode_mla
+from repro.kernels.ssd_scan import ssd_scan
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep the cache out of these compiles
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Make the dispatch layer and the kernels take their TPU branch while
+    tracing here: dispatch resolves to Pallas, and kernels whose interpret
+    flag follows the backend lower for the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dispatch.clear_caches()
+    yield
+    dispatch.clear_caches()
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _sds(sharding, shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# (q shape, kv shape): gpt2-350m (MHA, d_head 64) and llama3.2-3b (GQA 24/8)
+ATTN_WIDTHS = {
+    "gpt2-350m": ((4, 1024, 16, 64), (4, 1024, 16, 64)),
+    "llama3.2-3b": ((4, 1024, 24, 128), (4, 1024, 8, 128)),
+}
+
+
+@pytest.mark.parametrize("arch", sorted(ATTN_WIDTHS))
+def test_flash_attention_forward(one_chip, arch):
+    qs, ks = ATTN_WIDTHS[arch]
+    q, kv = _sds(one_chip, qs), _sds(one_chip, ks)
+    _compile(lambda q, k, v: flash_attention(q, k, v, interpret=False),
+             q, kv, kv)
+
+
+@pytest.mark.parametrize("arch", sorted(ATTN_WIDTHS))
+def test_flash_attention_grad(one_chip, tpu_backend, arch):
+    qs, ks = ATTN_WIDTHS[arch]
+    q, kv = _sds(one_chip, qs), _sds(one_chip, ks)
+    assert dispatch.resolve("attention")[0] == "pallas"
+
+    def loss(q, k, v):
+        return dispatch.attention(q, k, v, causal=True).astype(F32).sum()
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+
+# llama3.2-3b decode: the serve smoke's cache (128 prompt + 16 generated,
+# not a multiple of the 128-row block) and a 2k cache
+@pytest.mark.parametrize("cache_len", [144, 2048])
+def test_flash_decode_gqa(one_chip, cache_len):
+    q = _sds(one_chip, (4, 1, 24, 128))
+    kv = _sds(one_chip, (4, cache_len, 8, 128))
+    valid = _sds(one_chip, (4, cache_len), jnp.bool_)
+    _compile(lambda q, k, v, m: flash_decode_gqa(q, k, v, m,
+                                                  interpret=False),
+             q, kv, kv, valid)
+
+
+# deepseek-v2: 128 heads, latent rank 512, rope dim 64, denom sqrt(128 + 64)
+@pytest.mark.parametrize("cache_len", [144, 2048])
+def test_flash_decode_mla(one_chip, cache_len):
+    b, H, r, dr = 4, 128, 512, 64
+    args = (_sds(one_chip, (b, H, r)), _sds(one_chip, (b, H, dr)),
+            _sds(one_chip, (b, cache_len, r)),
+            _sds(one_chip, (b, cache_len, dr)),
+            _sds(one_chip, (b, cache_len), jnp.bool_))
+    _compile(lambda *a: flash_decode_mla(*a, denom=(128 + 64) ** 0.5,
+                                         interpret=False), *args)
+
+
+def _ssd_shapes(sharding):
+    # mamba2-130m: 24 heads of 64, state 128, batch 4 x seq 1024
+    b, s, h, p, n = 4, 1024, 24, 64, 128
+    return (_sds(sharding, (b, s, h, p)), _sds(sharding, (b, s, h)),
+            _sds(sharding, (h,), F32), _sds(sharding, (b, s, n)),
+            _sds(sharding, (b, s, n)), _sds(sharding, (h,), F32),
+            _sds(sharding, (h,), F32))
+
+
+def test_ssd_scan_forward(one_chip):
+    _compile(lambda *a: ssd_scan(*a, interpret=False),
+             *_ssd_shapes(one_chip))
+
+
+def test_ssd_scan_grad(one_chip, tpu_backend):
+    assert dispatch.resolve("ssd_scan")[0] == "pallas"
+
+    def loss(*a):
+        y, state = dispatch.ssd(*a)
+        return y.astype(F32).sum() + state.sum()
+    _compile(jax.value_and_grad(loss, argnums=tuple(range(7))),
+             *_ssd_shapes(one_chip))
+
+
+def test_adam_update_fused(one_chip):
+    flat = _sds(one_chip, (1 << 24,), F32)
+    _compile(lambda g, m, v, p: adam_update_fused(
+        g, m, v, p, lr=1e-3, beta1=0.9, beta2=0.95, eps=1e-8, wd=0.1,
+        c1=0.1, c2=0.1, interpret=False), flat, flat, flat, flat)
+
+
+def test_train_step_2x2_mesh(topo, tpu_backend):
+    """The whole gpt2-350m train step on a 2x2 (data x model) mesh: the
+    SPMD partitioner cannot split a Pallas call, so each kernel must run
+    per shard (``parallel.act.per_shard``)."""
+    from jax.sharding import AxisType, Mesh
+
+    from repro.configs.base import ShapeConfig, TrainConfig
+    from repro.configs.registry import get_arch
+    from repro.launch.inputs import train_inputs
+    from repro.train import build_train_step
+
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = get_arch("gpt2-350m")
+    tc = TrainConfig(global_batch=8, seq_len=1024, zero=1)
+    (state, batch), (s_sh, b_sh) = train_inputs(
+        cfg, ShapeConfig("train", 1024, 8, "train"), mesh, tc)
+    step, _ = build_train_step(cfg, tc, mesh, 8, 1024)
+    compiled = jax.jit(step, in_shardings=(s_sh, b_sh),
+                       donate_argnums=(0,)).lower(state, batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
