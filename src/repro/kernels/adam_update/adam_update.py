@@ -72,6 +72,7 @@ def adam_update_fused(g: jax.Array, m: jax.Array, v: jax.Array,
             jax.ShapeDtypeStruct((n_p,), jnp.bfloat16),
         ],
         interpret=interpret,
+        name="adam_update",
     )(scal, gf, mf, vf, pf)
     return (m2[:n].reshape(shape), v2[:n].reshape(shape),
             mp2[:n].reshape(shape), p2[:n].reshape(shape))

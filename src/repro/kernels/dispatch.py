@@ -15,6 +15,11 @@ The choice can be forced either way with the ``REPRO_KERNELS`` env var
 (``pallas`` | ``ref`` | ``auto``) or programmatically with the
 ``force()`` context manager (tests and benchmarks use the latter).
 
+Each public op (``attention``, ``ssd``, ``adam_update_leaf``,
+``flash_decode``, ``mla_flash_decode``) runs inside a ``jax.named_scope``
+of its own name, so every device op it lowers to (a kernel's backward
+included) carries that name in a profiler trace (``obs.device``).
+
 Resolution is memoized — after the first call per ``(op, backend,
 override)`` the lookup amortizes to a single dict hit, guarded by the
 perf smoke in tests/test_dispatch.py.  Implementation modules are
@@ -33,9 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# observability plane (decision-free): per-op call counters + opt-in
-# eager timing; one boolean read per public-op call when disabled
-from repro.obs.metrics import METRICS
+from repro.obs import device as obs_device
 from repro.parallel.act import logical_spec, per_shard
 
 ENV_VAR = "REPRO_KERNELS"
@@ -99,26 +102,6 @@ def resolve(op: str, backend: Optional[str] = None) -> Tuple[str, Callable]:
     out = (name, impls[name])
     _RESOLVE_CACHE[key] = out
     return out
-
-
-def call(op: str, *args, **kw):
-    if METRICS.enabled:
-        return _observed(op, resolve(op)[1], args, kw)
-    return resolve(op)[1](*args, **kw)
-
-
-def _observed(op: str, fn: Callable, args: tuple, kw: dict):
-    """Obs-enabled call path: count the op and, with ``op_timing`` opted
-    in, measure eager wall time per call (dispatch-side — the returned
-    array is *not* blocked on, so jit/async dispatch is unperturbed;
-    timings are skipped inside jit traces, where args are tracers)."""
-    METRICS.inc("ops/" + op)
-    if METRICS.op_timing and _concrete(*args):
-        t0 = time.perf_counter()
-        out = fn(*args, **kw)
-        METRICS.observe("ops_s/" + op, time.perf_counter() - t0)
-        return out
-    return fn(*args, **kw)
 
 
 # ------------------------------------------------------------ autotune ---
@@ -264,12 +247,10 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     The Pallas kernel is forward-only.  Its ``custom_vjp`` saves q, k, v
     and takes the backward as the VJP of the chunked ref
     (``models.attention.chunked_attention``), recomputed from them."""
-    if METRICS.enabled:
-        return _observed("attention", resolve("attention")[1], (q, k, v),
-                         dict(causal=causal, window=window,
-                              softmax_scale=softmax_scale))
-    return resolve("attention")[1](q, k, v, causal=causal, window=window,
-                                   softmax_scale=softmax_scale)
+    with jax.named_scope(obs_device.ATTENTION):
+        return resolve("attention")[1](q, k, v, causal=causal,
+                                       window=window,
+                                       softmax_scale=softmax_scale)
 
 
 def _flash_decode_ref(kind, *args, **kw):
@@ -314,12 +295,9 @@ def flash_decode(q, k_cache, v_cache, valid, *,
     Returns (b, 1, H, D).  TPU: split-KV Pallas kernel (parallel over
     cache blocks, two-pass online-softmax reduction); CPU/GPU: ref
     bit-identical to the seed ``decode_attention``."""
-    if METRICS.enabled:
-        return _observed("flash_decode", resolve("flash_decode")[1],
-                         ("gqa", q, k_cache, v_cache, valid),
-                         dict(softmax_scale=softmax_scale))
-    return resolve("flash_decode")[1]("gqa", q, k_cache, v_cache, valid,
-                                      softmax_scale=softmax_scale)
+    with jax.named_scope(obs_device.FLASH_DECODE):
+        return resolve("flash_decode")[1]("gqa", q, k_cache, v_cache, valid,
+                                          softmax_scale=softmax_scale)
 
 
 def mla_flash_decode(q_lat, q_rope, c_kv, k_rope, valid, *, denom: float):
@@ -328,12 +306,9 @@ def mla_flash_decode(q_lat, q_rope, c_kv, k_rope, valid, *, denom: float):
     q_lat: (b, H, r); q_rope: (b, H, dr); c_kv: (b, S, r); k_rope:
     (b, S, dr); valid: (b, S) bool; denom = sqrt(dn + dr).  Returns
     o_lat (b, H, r)."""
-    if METRICS.enabled:
-        return _observed("mla_flash_decode", resolve("flash_decode")[1],
-                         ("mla", q_lat, q_rope, c_kv, k_rope, valid),
-                         dict(denom=denom))
-    return resolve("flash_decode")[1]("mla", q_lat, q_rope, c_kv, k_rope,
-                                      valid, denom=denom)
+    with jax.named_scope(obs_device.MLA_FLASH_DECODE):
+        return resolve("flash_decode")[1]("mla", q_lat, q_rope, c_kv,
+                                          k_rope, valid, denom=denom)
 
 
 def _ssd_ref(x, dt_raw, A_log, B, C, D, dt_bias, *, chunk: int = 128):
@@ -398,12 +373,9 @@ def ssd(x, dt_raw, A_log, B, C, D, dt_bias, *, chunk: int = 128):
     inputs and takes the backward as the VJP of the chunked ref
     (``models.mamba2.ssd_chunked`` at the caller's ``chunk``), recomputed
     from them."""
-    if METRICS.enabled:
-        return _observed("ssd_scan", resolve("ssd_scan")[1],
-                         (x, dt_raw, A_log, B, C, D, dt_bias),
-                         dict(chunk=chunk))
-    return resolve("ssd_scan")[1](x, dt_raw, A_log, B, C, D, dt_bias,
-                                  chunk=chunk)
+    with jax.named_scope(obs_device.SSD):
+        return resolve("ssd_scan")[1](x, dt_raw, A_log, B, C, D, dt_bias,
+                                      chunk=chunk)
 
 
 def _adam_ref(g, m, v, master, *, lr, beta1: float, beta2: float,
@@ -451,14 +423,10 @@ def adam_update_leaf(g, m, v, master, *, lr, beta1: float, beta2: float,
     lr/c1/c2 may be traced.  ``spec`` is the leaf's PartitionSpec on the
     active mesh; the Pallas kernel runs once per shard of it.  Returns
     (m', v', master')."""
-    if METRICS.enabled:
-        return _observed("adam_update", resolve("adam_update")[1],
-                         (g, m, v, master),
-                         dict(lr=lr, beta1=beta1, beta2=beta2, eps=eps,
-                              wd=wd, c1=c1, c2=c2, spec=spec))
-    return resolve("adam_update")[1](g, m, v, master, lr=lr, beta1=beta1,
-                                     beta2=beta2, eps=eps, wd=wd,
-                                     c1=c1, c2=c2, spec=spec)
+    with jax.named_scope(obs_device.ADAM_UPDATE_LEAF):
+        return resolve("adam_update")[1](g, m, v, master, lr=lr,
+                                         beta1=beta1, beta2=beta2, eps=eps,
+                                         wd=wd, c1=c1, c2=c2, spec=spec)
 
 
 register("attention", pallas=_attention_pallas, ref=_attention_ref)

@@ -128,6 +128,7 @@ def flash_decode_gqa(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             jax.ShapeDtypeStruct((b, ns, K, G, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode_gqa",
     )(q.reshape(b, K, G, D), k_cache, v_cache, vmask[:, None, :])
     out = _combine(acc, m[..., 0], l[..., 0], v_cache.dtype)  # (b, K, G, D)
     return out.reshape(b, 1, H, D)
@@ -197,5 +198,6 @@ def flash_decode_mla(q_lat: jax.Array, q_rope: jax.Array, c_kv: jax.Array,
             jax.ShapeDtypeStruct((b, ns, H, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode_mla",
     )(q_lat, q_rope, c_kv, k_rope, vmask[:, None, :])
     return _combine(acc, m[..., 0], l[..., 0], c_kv.dtype)   # (b, H, r)

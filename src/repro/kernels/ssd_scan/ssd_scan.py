@@ -131,6 +131,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A_log: jax.Array, B: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan_fwd",
     )(xh, dt[..., None], dt[:, :, None, :], A, B, C,
       D.astype(jnp.float32))
     return y[:, :, :s].swapaxes(1, 2), st

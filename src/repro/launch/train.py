@@ -20,24 +20,25 @@ from repro.core import memtrace
 from repro.data import SyntheticTokens
 from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_plan_mesh
+from repro.obs import device as obs_device
 from repro.train import build_train_step, make_train_state, state_specs
 from repro import ckpt as ckpt_mod
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 def record_compile_telemetry(step_jit, state, batch, cfg, tc, d: int,
-                             t: int) -> object:
+                             t: int) -> None:
     """AOT-compile the jitted step and feed its XLA memory accounting into
     the memory feedback plane (``core.memtrace``) — the live-compile
-    telemetry source.  Returns the compiled executable so the caller can
-    drive the loop with it (one compile, not two).  A failed compile fails
-    the run; a backend without ``memory_analysis`` is reported."""
+    telemetry source.  The jitted step reuses this executable on its first
+    call (one compile, not two).  A failed compile fails the run; a backend
+    without ``memory_analysis`` is reported."""
     compiled = step_jit.lower(state, batch).compile()
     ma = compiled.memory_analysis()
     if ma is None:
         print("memtrace: this backend reports no memory_analysis",
               flush=True)
-        return compiled
+        return
     observed = mm.xla_peak_bytes(ma)
     pred = mm.exact_peak_bytes(cfg, tc.global_batch, tc.seq_len, d, t,
                                zero=tc.zero, microbatch=tc.microbatch)
@@ -47,7 +48,6 @@ def record_compile_telemetry(step_jit, state, batch, cfg, tc, d: int,
     print(f"memtrace: observed peak {observed / 2**30:.2f} GiB vs"
           f" predicted {pred / 2**30:.2f} GiB"
           f" ({dev_type}, zero={tc.zero})", flush=True)
-    return compiled
 
 
 def run(cfg, tc: TrainConfig, mesh, *, log_every: int = 10):
@@ -64,19 +64,20 @@ def run(cfg, tc: TrainConfig, mesh, *, log_every: int = 10):
 
     it = iter(SyntheticTokens(cfg, batch, seq, seed=tc.seed))
 
-    def prep(raw):
-        return {k: jnp.asarray(v) for k, v in raw.items()
-                if k in ("tokens", "labels", "modal_embeds")}
+    def prep():
+        with obs_device.span(obs_device.DATA):
+            return {k: jnp.asarray(v) for k, v in next(it).items()
+                    if k in ("tokens", "labels", "modal_embeds")}
 
-    # one AOT compile: drives the loop below *and* feeds observed peak
-    # memory into the feedback plane (batch shapes are static, so the
-    # compiled executable serves every step)
-    first = prep(next(it))
-    step_fn = record_compile_telemetry(step_jit, state, first, cfg, tc, d, t)
+    # one AOT compile: the jitted step runs it in the loop below, and its
+    # observed peak memory feeds the feedback plane (batch shapes are
+    # static, so one executable serves every step)
+    first = prep()
+    record_compile_telemetry(step_jit, state, first, cfg, tc, d, t)
     losses = []
     t0 = time.time()
     for i in range(tc.steps):
-        state, metrics = step_fn(state, first if i == 0 else prep(next(it)))
+        state, metrics = step_jit(state, first if i == 0 else prep())
         losses.append(float(metrics["loss"]))
         if i % log_every == 0 or i == tc.steps - 1:
             dt = time.time() - t0

@@ -1,12 +1,13 @@
 """Observability plane (opt-in, decision-free).
 
 ``obs.enable()`` turns on the structured event tracer (``obs.trace``) and
-the metrics registry (``obs.metrics``); the lifecycle engine, cluster
-pool, and kernel dispatch then feed them — spans, instants, counters,
-downsampled time series — at bounded memory.  ``obs.export`` renders a
-Chrome-trace JSON (Perfetto / ``chrome://tracing``) and a metrics dump;
+the metrics registry (``obs.metrics``); the lifecycle engine and cluster
+pool then feed them — spans, instants, counters, downsampled time
+series — at bounded memory.  ``obs.export`` renders a Chrome-trace JSON
+(Perfetto / ``chrome://tracing``) and a metrics dump;
 ``python -m repro.obs.report`` summarizes either a live registry or the
-exported files.
+exported files.  The device path is named for the JAX profiler instead
+(``obs.device``: scopes, host spans, compile counter).
 
 Contract (ROADMAP "Observability plane"): telemetry is free — no decision
 ever reads obs state, and every placement/timestamp is bit-identical with
@@ -25,12 +26,10 @@ from repro.obs.trace import TRACER
 
 def enable(*, trace_capacity: Optional[int] = None,
            max_points: Optional[int] = None,
-           sample_stride: Optional[int] = None,
-           op_timing: bool = False) -> None:
+           sample_stride: Optional[int] = None) -> None:
     """Enable tracing + metrics (clears any previous run's data)."""
     TRACER.enable(capacity=trace_capacity)
-    METRICS.enable(op_timing=op_timing, max_points=max_points,
-                   sample_stride=sample_stride)
+    METRICS.enable(max_points=max_points, sample_stride=sample_stride)
 
 
 def disable() -> None:

@@ -9,9 +9,10 @@ Fed (only when enabled) by
 * the admission path — queue depth series, admission-latency histogram
   (first-start wait), admitted-job counter;
 * the serve plane — rolling SLO attainment (good/total accounted seconds)
-  and the live replica count;
-* ``kernels.dispatch`` — per-op call counters and, opt-in
-  (``op_timing=True``), eager per-op wall-time histograms.
+  and the live replica count.
+
+The device path (the jitted train step and its kernels) is measured from
+the profiler's trace instead: see ``obs.device``.
 
 Everything is pure accumulation (telemetry-is-free invariant): no decision
 reads the registry, and memory is bounded — a ``TimeSeries`` holds at most
@@ -177,7 +178,6 @@ class MetricsRegistry:
     def __init__(self):
         self.enabled = False
         self.version = 0                    # bumps per enable (token)
-        self.op_timing = False              # opt-in eager op timing
         self.max_points = DEFAULT_MAX_POINTS
         self.sample_stride = DEFAULT_SAMPLE_STRIDE
         self.counters: Dict[str, float] = {}
@@ -185,15 +185,13 @@ class MetricsRegistry:
         self.hists: Dict[str, Histogram] = {}
 
     # ------------------------------------------------------------ control
-    def enable(self, *, op_timing: bool = False,
-               max_points: Optional[int] = None,
+    def enable(self, *, max_points: Optional[int] = None,
                sample_stride: Optional[int] = None) -> None:
         """Start collecting (clears any previous run's data)."""
         if max_points is not None:
             self.max_points = int(max_points)
         if sample_stride is not None:
             self.sample_stride = max(int(sample_stride), 1)
-        self.op_timing = bool(op_timing)
         self.counters = {}
         self.series = {}
         self.hists = {}
@@ -204,7 +202,6 @@ class MetricsRegistry:
         """Stop collecting; data is kept for export until ``clear()`` or
         the next ``enable()``."""
         self.enabled = False
-        self.op_timing = False
 
     def clear(self) -> None:
         self.counters = {}

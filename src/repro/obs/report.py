@@ -127,12 +127,6 @@ def report(trace: dict, metrics: dict, top: int = 10,
             continue
         print(f"{name}: n={h['total']} mean={h['mean']:.3g}s"
               f" p50<={h['p50']:.3g}s p95<={h['p95']:.3g}s", file=out)
-    ops = {k: v for k, v in metrics.get("counters", {}).items()
-           if k.startswith("ops/")}
-    if ops:
-        print("kernel op calls: "
-              + "  ".join(f"{k[4:]}={int(v)}" for k, v in sorted(
-                  ops.items())), file=out)
 
 
 def _demo(out=sys.stdout) -> int:

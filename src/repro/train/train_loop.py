@@ -13,6 +13,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.models import forward, cross_entropy, init_params
+from repro.obs import device as obs_device
 from repro.parallel import sharding as sh
 from repro.parallel.act import activation_sharding
 from repro.train.optimizer import adam_update, init_opt_state
@@ -64,7 +65,10 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh,
 
     jit=True returns the step already jitted with the state buffers donated
     (argnums 0): params/opt/m/v are rewritten in place instead of
-    double-buffered, halving the optimizer-state working set.  jit=False
+    double-buffered, halving the optimizer-state working set.  It is wrapped
+    in ``obs.device.TracedStep``: each call opens the ``repro/train_step``
+    host span.  Either way the step's ops carry the ``obs.device`` scopes
+    (``model``, ``grad_accum``, ``optimizer``) in their names.  jit=False
     (default) returns the traceable step for callers that lower it with
     explicit shardings (launch.dryrun) or wrap it themselves.
     """
@@ -79,11 +83,12 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh,
         batch = {"tokens": micro["tokens"]}
         if "modal_embeds" in micro:
             batch["modal_embeds"] = micro["modal_embeds"]
-        logits, aux, _ = forward(cfg, params, batch,
-                                 remat=tc.remat != "none")
-        # labels cover the full (modal + text) sequence
-        ce = cross_entropy(logits[:, :-1], micro["labels"][:, 1:])
-        return ce + AUX_WEIGHT * aux, ce
+        with jax.named_scope(obs_device.MODEL):
+            logits, aux, _ = forward(cfg, params, batch,
+                                     remat=tc.remat != "none")
+            # labels cover the full (modal + text) sequence
+            ce = cross_entropy(logits[:, :-1], micro["labels"][:, 1:])
+            return ce + AUX_WEIGHT * aux, ce
 
     grad_fn = jax.value_and_grad(micro_loss, has_aux=True)
 
@@ -106,29 +111,34 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh,
         def accum(carry, micro):
             g_acc, loss_acc = carry
             (loss, ce), g = grad_fn(params, micro)
-            g = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
-                             g_acc, g)
-            g = jax.tree_util.tree_map(
-                lambda x, s: constrain(x, s), g, opt_spec)
+            with jax.named_scope(obs_device.GRAD_ACCUM):
+                g = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
+                                 g_acc, g)
+                g = jax.tree_util.tree_map(
+                    lambda x, s: constrain(x, s), g, opt_spec)
             return (g, loss_acc + ce), None
 
-        g0 = jax.tree.map(
-            lambda p, s: constrain(jnp.zeros(p.shape, jnp.float32), s),
-            params, opt_spec)
+        with jax.named_scope(obs_device.GRAD_ACCUM):
+            g0 = jax.tree.map(
+                lambda p, s: constrain(jnp.zeros(p.shape, jnp.float32), s),
+                params, opt_spec)
         (g_sum, loss_sum), _ = jax.lax.scan(accum, (g0, 0.0), micros)
-        grads = jax.tree.map(lambda g: g / n_micro, g_sum)
-        new_params, new_opt, gnorm = adam_update(
-            tc, params, state["opt"], grads, state["step"], opt_spec)
-        # hand the params back in the layout they came in: left free, the
-        # compiler shards them like the ZeRO master copy they are cast
-        # from, and a compiled step then refuses its own output next step
-        p_spec = sh.param_specs(cfg, params, mesh, zero_data=tc.zero >= 3)
-        new_params = jax.tree.map(constrain, new_params, p_spec)
+        with jax.named_scope(obs_device.OPTIMIZER):
+            grads = jax.tree.map(lambda g: g / n_micro, g_sum)
+            new_params, new_opt, gnorm = adam_update(
+                tc, params, state["opt"], grads, state["step"], opt_spec)
+            # hand the params back in the layout they came in: left free,
+            # the compiler shards them like the ZeRO master copy they are
+            # cast from, and a compiled step then refuses its own output
+            # next step
+            p_spec = sh.param_specs(cfg, params, mesh,
+                                    zero_data=tc.zero >= 3)
+            new_params = jax.tree.map(constrain, new_params, p_spec)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         metrics = {"loss": loss_sum / n_micro, "grad_norm": gnorm}
         return new_state, metrics
 
     if jit:
-        step = jax.jit(step, donate_argnums=(0,))
+        step = obs_device.TracedStep(jax.jit(step, donate_argnums=(0,)))
     return step, n_micro

@@ -344,25 +344,3 @@ def test_streamed_sim_with_obs_stays_bounded():
             assert len(ts) < 2 * 64
     finally:
         obs.disable()
-
-
-# ---------------------------------------------------- kernel dispatch ----
-
-def test_dispatch_op_counters_and_timing():
-    from repro.kernels import dispatch
-
-    def impl(x):
-        return x + 1
-
-    dispatch.register("obs_test_op", pallas=impl, ref=impl)
-    try:
-        assert dispatch.call("obs_test_op", 1) == 2     # obs off: plain
-        obs.enable(op_timing=True)
-        from repro.obs.metrics import METRICS
-        for i in range(5):
-            assert dispatch.call("obs_test_op", i) == i + 1
-        assert METRICS.counter("ops/obs_test_op") == 5
-        h = METRICS.hists["ops_s/obs_test_op"]
-        assert h.total == 5 and h.sum >= 0.0
-    finally:
-        obs.disable()

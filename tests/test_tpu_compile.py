@@ -6,7 +6,7 @@ dispatch layer's ``custom_vjp``, taken with ``value_and_grad`` as the
 train step does: ``grad`` alone needs no forward output, and the kernel
 would be dead code) for one chip of a described ``v5e:2x2``
 topology and checks that the kernel is in the program
-(``tpu_custom_call``).  Nothing runs; no chip is needed.
+(``tpu_custom_call``) under its own name, which names it in a trace.  Nothing runs; no chip is needed.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU library, and every test worker
@@ -15,6 +15,7 @@ imports this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,9 +67,14 @@ def tpu_backend(monkeypatch):
     dispatch.clear_caches()
 
 
-def _compile(fn, *shapes):
+def _compile(kernel, fn, *shapes):
+    """Compile ``fn`` and check that the Pallas call named ``kernel`` is
+    in the program: the name is its HLO instruction's."""
     compiled = jax.jit(fn).lower(*shapes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(r"%" + kernel + r"(\.\d+)? = .*custom_call_target="
+                     r'"tpu_custom_call"', text), kernel
     return compiled
 
 
@@ -87,7 +93,8 @@ ATTN_WIDTHS = {
 def test_flash_attention_forward(one_chip, arch):
     qs, ks = ATTN_WIDTHS[arch]
     q, kv = _sds(one_chip, qs), _sds(one_chip, ks)
-    _compile(lambda q, k, v: flash_attention(q, k, v, interpret=False),
+    _compile("flash_attention_fwd",
+             lambda q, k, v: flash_attention(q, k, v, interpret=False),
              q, kv, kv)
 
 
@@ -99,7 +106,8 @@ def test_flash_attention_grad(one_chip, tpu_backend, arch):
 
     def loss(q, k, v):
         return dispatch.attention(q, k, v, causal=True).astype(F32).sum()
-    _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    _compile("flash_attention_fwd",
+             jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
 
 
 # llama3.2-3b decode: the serve smoke's cache (128 prompt + 16 generated,
@@ -109,8 +117,9 @@ def test_flash_decode_gqa(one_chip, cache_len):
     q = _sds(one_chip, (4, 1, 24, 128))
     kv = _sds(one_chip, (4, cache_len, 8, 128))
     valid = _sds(one_chip, (4, cache_len), jnp.bool_)
-    _compile(lambda q, k, v, m: flash_decode_gqa(q, k, v, m,
-                                                  interpret=False),
+    _compile("flash_decode_gqa",
+             lambda q, k, v, m: flash_decode_gqa(q, k, v, m,
+                                                 interpret=False),
              q, kv, kv, valid)
 
 
@@ -122,7 +131,8 @@ def test_flash_decode_mla(one_chip, cache_len):
             _sds(one_chip, (b, cache_len, r)),
             _sds(one_chip, (b, cache_len, dr)),
             _sds(one_chip, (b, cache_len), jnp.bool_))
-    _compile(lambda *a: flash_decode_mla(*a, denom=(128 + 64) ** 0.5,
+    _compile("flash_decode_mla",
+             lambda *a: flash_decode_mla(*a, denom=(128 + 64) ** 0.5,
                                          interpret=False), *args)
 
 
@@ -136,7 +146,7 @@ def _ssd_shapes(sharding):
 
 
 def test_ssd_scan_forward(one_chip):
-    _compile(lambda *a: ssd_scan(*a, interpret=False),
+    _compile("ssd_scan_fwd", lambda *a: ssd_scan(*a, interpret=False),
              *_ssd_shapes(one_chip))
 
 
@@ -146,13 +156,14 @@ def test_ssd_scan_grad(one_chip, tpu_backend):
     def loss(*a):
         y, state = dispatch.ssd(*a)
         return y.astype(F32).sum() + state.sum()
-    _compile(jax.value_and_grad(loss, argnums=tuple(range(7))),
+    _compile("ssd_scan_fwd",
+             jax.value_and_grad(loss, argnums=tuple(range(7))),
              *_ssd_shapes(one_chip))
 
 
 def test_adam_update_fused(one_chip):
     flat = _sds(one_chip, (1 << 24,), F32)
-    _compile(lambda g, m, v, p: adam_update_fused(
+    _compile("adam_update", lambda g, m, v, p: adam_update_fused(
         g, m, v, p, lr=1e-3, beta1=0.9, beta2=0.95, eps=1e-8, wd=0.1,
         c1=0.1, c2=0.1, interpret=False), flat, flat, flat, flat)
 
