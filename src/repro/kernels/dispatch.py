@@ -3,8 +3,9 @@
 Every compute hot-spot (``attention``, ``flash_decode``, ``ssd_scan``,
 ``adam_update``) registers two implementations:
 
-* ``pallas`` — the TPU kernel (``repro.kernels.*``), with block sizes
-  resolved through a per-process autotune cache keyed on
+* ``pallas`` — the TPU kernel (``repro.kernels.*``); attention's blocks
+  come from its shape (``flash_attention_tiling``), the other kernels'
+  through a per-process autotune cache keyed on
   ``(op, shape-bucket, dtype, backend)``;
 * ``ref`` — the chunked pure-jnp production path (``repro.models.*`` /
   the per-leaf optimizer math), **bit-identical** to the pre-dispatch
@@ -189,21 +190,19 @@ def _attention_ref(q, k, v, *, causal: bool = True, window: int = 0,
                              softmax_scale=softmax_scale)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_attention_vjp(q, k, v, causal, window, softmax_scale, block_q,
-                         block_k):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_attention_vjp(q, k, v, causal, window, softmax_scale):
     from repro.kernels.flash_attention import flash_attention
+    # the kernel takes its blocks from the shape (flash_attention_tiling)
     return flash_attention(q, k, v, causal=causal, window=window,
-                           softmax_scale=softmax_scale, block_q=block_q,
-                           block_k=block_k)
+                           softmax_scale=softmax_scale)
 
 
 def _flash_attention_fwd(q, k, v, *static):
     return _flash_attention_vjp(q, k, v, *static), (q, k, v)
 
 
-def _flash_attention_bwd(causal, window, softmax_scale, block_q, block_k,
-                         res, g):
+def _flash_attention_bwd(causal, window, softmax_scale, res, g):
     _, vjp = jax.vjp(functools.partial(_attention_ref, causal=causal,
                                        window=window,
                                        softmax_scale=softmax_scale), *res)
@@ -215,28 +214,12 @@ _flash_attention_vjp.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 def _attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                       softmax_scale: Optional[float] = None):
-    from repro.kernels.flash_attention import flash_attention
-
-    def thunk_for(params):
-        def thunk():
-            return flash_attention(q, k, v, causal=causal, window=window,
-                                   softmax_scale=softmax_scale,
-                                   **params).block_until_ready()
-        return thunk
-
-    params = autotuned(
-        "attention", (q.shape[1], k.shape[1], q.shape[-1]), q.dtype,
-        candidates=[{"block_q": bq, "block_k": bk}
-                    for bq in (128, 256) for bk in (128, 256)],
-        default={"block_q": 128, "block_k": 128},
-        make_thunk=thunk_for if _concrete(q, k, v) else None)
     # per (batch, head) shard: attention never mixes either
     qs = logical_spec(q.shape, "batch", None, "heads", None)
     ks = logical_spec(k.shape, "batch", None, "heads", None)
     return per_shard(
-        lambda q, k, v: _flash_attention_vjp(
-            q, k, v, causal, window, softmax_scale, params["block_q"],
-            params["block_k"]),
+        lambda q, k, v: _flash_attention_vjp(q, k, v, causal, window,
+                                             softmax_scale),
         (qs, ks, ks), qs)(q, k, v)
 
 

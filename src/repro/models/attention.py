@@ -2,7 +2,7 @@
 single-token decode attention, and the GQA / MLA layer implementations.
 
 Full-sequence attention goes through ``repro.kernels.dispatch``: on TPU the
-Pallas flash kernel runs (autotuned block sizes); on CPU/GPU the chunked
+Pallas flash kernel runs (blocks from the shape); on CPU/GPU the chunked
 implementation below runs, bit-identical to calling it directly.  The Pallas
 kernel is numerically validated against ``repro.kernels.flash_attention.ref``
 which in turn matches this module.

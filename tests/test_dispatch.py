@@ -81,25 +81,33 @@ def test_resolve_overhead_amortizes_to_dict_hit():
 
 def test_autotune_cache_keying():
     dispatch.clear_caches()
-    key = jax.random.PRNGKey(0)
-    ks = jax.random.split(key, 3)
+
+    def tune(dims, dtype, **kw):
+        return dispatch.autotuned("op", dims, dtype,
+                                  candidates=[{"block": 64}, {"block": 128}],
+                                  default={"block": 64}, **kw)
+
+    assert tune((64, 32), jnp.float32) == {"block": 64}   # CPU: the default
+    tune((60, 32), jnp.float32)                    # same bucket: no new key
+    info1 = dispatch.autotune_cache_info()
+    assert len(info1) == 1
+    (op, bucket, dtype, backend), params = next(iter(info1.items()))
+    assert op == "op" and bucket == (64, 32) and dtype == "float32"
+    assert backend == jax.default_backend()
+    tune((32, 32), jnp.float32)                    # new bucket
+    assert len(dispatch.autotune_cache_info()) == 2
+    tune((64, 32), jnp.bfloat16)                   # new dtype key
+    assert len(dispatch.autotune_cache_info()) == 3
+    tune((64, 32), jnp.float32, exact=(7,))        # exact key component
+    assert len(dispatch.autotune_cache_info()) == 4
+    # attention's blocks come from the kernel's own tiling, not the cache
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (1, 64, 4, 32), jnp.float32)
     k = jax.random.normal(ks[1], (1, 64, 2, 32), jnp.float32)
     v = jax.random.normal(ks[2], (1, 64, 2, 32), jnp.float32)
     with dispatch.force("pallas"):
         dispatch.attention(q, k, v)
-        dispatch.attention(q * 2, k, v)                # same bucket: no new key
-        info1 = dispatch.autotune_cache_info()
-        assert len(info1) == 1
-        (op, bucket, dtype, backend), params = next(iter(info1.items()))
-        assert op == "attention" and dtype == "float32"
-        assert backend == jax.default_backend()
-        assert params == {"block_q": 128, "block_k": 128}   # CPU heuristic
-        dispatch.attention(q[:, :32], k, v)            # new seq bucket
-        assert len(dispatch.autotune_cache_info()) == 2
-        dispatch.attention(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
-                           v.astype(jnp.bfloat16))     # new dtype key
-        assert len(dispatch.autotune_cache_info()) == 3
+    assert len(dispatch.autotune_cache_info()) == 4
     dispatch.clear_caches()
 
 

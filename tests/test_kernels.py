@@ -6,6 +6,9 @@ import pytest
 
 from repro.kernels.adam_update import adam_ref, adam_update_fused
 from repro.kernels.flash_attention import attention_ref, flash_attention
+from repro.kernels.flash_attention.flash_attention import (
+    VMEM_BUDGET, block_live, feasible_tilings, flash_attention_tiling,
+    kv_span, vmem_bytes)
 from repro.kernels.ssd_scan import ssd_ref, ssd_scan
 
 
@@ -18,18 +21,92 @@ from repro.kernels.ssd_scan import ssd_ref, ssd_scan
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_sweep(b, sq, sk, H, K, D, causal, window, dtype):
+    _check_flash(b, sq, sk, H, K, D, causal, window, 64, 64, dtype)
+
+
+def _check_flash(b, sq, sk, H, K, D, causal, window, bq, bk, dtype):
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (b, sq, H, D), dtype)
     k = jax.random.normal(ks[1], (b, sk, K, D), dtype)
     v = jax.random.normal(ks[2], (b, sk, K, D), dtype)
     out = flash_attention(q, k, v, causal=causal, window=window,
-                          block_q=64, block_k=64, interpret=True)
+                          block_q=bq, block_k=bk, interpret=True)
     ref = attention_ref(q, k, v, causal=causal, window=window)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=tol, rtol=tol)
+
+
+# blocks of the shape-derived tiling (None: the tiling's own pick)
+@pytest.mark.parametrize("b,sq,sk,H,K,D,causal,window,bq,bk", [
+    (1, 512, 512, 2, 2, 64, True, 0, 128, 256),     # bq < bk
+    (1, 512, 512, 2, 1, 64, True, 0, 256, 128),     # bq > bk, MQA
+    (1, 1024, 1024, 2, 2, 64, True, 0, 256, 256),   # clamped dead steps
+    (1, 1024, 1024, 2, 1, 64, True, 300, 256, 256),  # window skips blocks
+    (1, 96, 96, 6, 3, 128, True, 0, None, None),    # one whole block
+    (1, 1000, 1000, 2, 2, 64, True, 0, None, None),  # padded whole block
+    (1, 1000, 1000, 2, 2, 64, True, 0, 256, 512),   # padded tail block
+    (2, 64, 192, 4, 1, 64, False, 0, None, None),   # cross-length
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_tiled(b, sq, sk, H, K, D, causal, window, bq, bk,
+                               dtype):
+    _check_flash(b, sq, sk, H, K, D, causal, window, bq, bk, dtype)
+
+
+def _live_steps(sq, sk, bq, bk, causal, window):
+    """Grid steps a (batch, head) that compute: the block pairs the
+    kernel's ``block_live`` keeps.  Each q block's live steps are the whole
+    of its ``kv_span``, so clamping the K/V index map to the span leaves
+    every live step's block in place."""
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    n = 0
+    for iq in range(nq):
+        live = [ik for ik in range(nk)
+                if block_live(iq * bq, ik * bk, bq, bk, causal, window)]
+        first, last = kv_span(iq * bq, bq, bk, nk, causal, window)
+        assert live == list(range(int(first), int(last) + 1)), (iq, live)
+        n += len(live)
+    return n
+
+
+def _band_blocks(sq, sk, bq, bk, causal, window):
+    """Block pairs holding a (q, k) pair inside the band, counted over the
+    whole (padded) score matrix."""
+    sq_p, sk_p = -(-sq // bq) * bq, -(-sk // bk) * bk
+    qp = np.arange(sq_p)[:, None]
+    kp = np.arange(sk_p)[None, :]
+    ok = kp < sk
+    if causal:
+        ok = ok & (kp <= qp)
+    if window:
+        ok = ok & (kp > qp - window)
+    return int(ok.reshape(sq_p // bq, bq, sk_p // bk, bk)
+               .any(axis=(1, 3)).sum())
+
+
+# (sq, sk, D, window) of the widths that call the kernel: gpt2-350m,
+# llama3.2-3b, MLA (q/k carry the rope part), starcoder2 at 8192 with its
+# window, and two serve prompt lengths
+@pytest.mark.parametrize("sq,sk,D,window", [
+    (1024, 1024, 64, 0), (1024, 1024, 128, 0), (1024, 1024, 192, 0),
+    (8192, 8192, 128, 4096), (37, 37, 128, 0), (144, 144, 128, 0),
+])
+def test_flash_attention_tiling(sq, sk, D, window):
+    bq, bk = flash_attention_tiling(sq, sk, D, jnp.bfloat16)
+    for blk, s in ((bq, sq), (bk, sk)):
+        # the (8, 128) rule, or the whole (padded) sequence
+        assert blk % 8 == 0 and (blk % 128 == 0 or blk >= s), (blk, s)
+    assert vmem_bytes(bq, bk, D, jnp.bfloat16) <= VMEM_BUDGET
+    tilings = feasible_tilings(sq, sk, D, jnp.bfloat16)
+    assert (bq, bk) in tilings
+    for tq, tk in tilings:
+        assert _live_steps(sq, sk, tq, tk, True, window) == \
+            _band_blocks(sq, sk, tq, tk, True, window), (tq, tk)
+    if (sq, D) == (1024, 64):                       # gpt2-350m's cell
+        assert (bq, bk) == (1024, 1024)
 
 
 @pytest.mark.parametrize("b,s,h,p,n,chunk", [

@@ -82,30 +82,43 @@ def _sds(sharding, shape, dtype=BF16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-# (q shape, kv shape): gpt2-350m (MHA, d_head 64) and llama3.2-3b (GQA 24/8)
+# (q shape, kv shape, window): gpt2-350m (MHA, d_head 64), llama3.2-3b
+# (GQA 24/8), deepseek-v2's MLA (q/k carry the rope part: 128 + 64) and
+# starcoder2-3b at 8192 tokens with its 4096 window, batch 1, on one chip
 ATTN_WIDTHS = {
-    "gpt2-350m": ((4, 1024, 16, 64), (4, 1024, 16, 64)),
-    "llama3.2-3b": ((4, 1024, 24, 128), (4, 1024, 8, 128)),
+    "gpt2-350m": ((4, 1024, 16, 64), (4, 1024, 16, 64), 0),
+    "llama3.2-3b": ((4, 1024, 24, 128), (4, 1024, 8, 128), 0),
+    "deepseek-v2-mla": ((1, 1024, 128, 192), (1, 1024, 128, 192), 0),
+    "starcoder2-3b": ((1, 8192, 24, 128), (1, 8192, 2, 128), 4096),
+}
+# the grad at starcoder2-3b's width runs on one chip of a model axis of 2
+# (12 of 24 heads, 1 of 2 KV heads): with all its heads the chunked ref's
+# backward needs more than the chip's HBM
+ATTN_GRAD_WIDTHS = {
+    **ATTN_WIDTHS,
+    "starcoder2-3b": ((1, 8192, 12, 128), (1, 8192, 1, 128), 4096),
 }
 
 
 @pytest.mark.parametrize("arch", sorted(ATTN_WIDTHS))
 def test_flash_attention_forward(one_chip, arch):
-    qs, ks = ATTN_WIDTHS[arch]
+    qs, ks, window = ATTN_WIDTHS[arch]
     q, kv = _sds(one_chip, qs), _sds(one_chip, ks)
     _compile("flash_attention_fwd",
-             lambda q, k, v: flash_attention(q, k, v, interpret=False),
+             lambda q, k, v: flash_attention(q, k, v, window=window,
+                                             interpret=False),
              q, kv, kv)
 
 
-@pytest.mark.parametrize("arch", sorted(ATTN_WIDTHS))
+@pytest.mark.parametrize("arch", sorted(ATTN_GRAD_WIDTHS))
 def test_flash_attention_grad(one_chip, tpu_backend, arch):
-    qs, ks = ATTN_WIDTHS[arch]
+    qs, ks, window = ATTN_GRAD_WIDTHS[arch]
     q, kv = _sds(one_chip, qs), _sds(one_chip, ks)
     assert dispatch.resolve("attention")[0] == "pallas"
 
     def loss(q, k, v):
-        return dispatch.attention(q, k, v, causal=True).astype(F32).sum()
+        return dispatch.attention(q, k, v, causal=True,
+                                  window=window).astype(F32).sum()
     _compile("flash_attention_fwd",
              jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
 
