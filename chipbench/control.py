@@ -43,16 +43,19 @@ def readings(cell: spec.Cell, seed: int, kinds=("control", "half_batch")):
     m, t = dict(cell.config["model"]), cell.traffic
     cfg = harness.ModelConfig(**m)
     key = weights.seed_key(seed)
-    p0 = jax.jit(lambda k: weights.make_params(cfg, k))(key)
+    init = harness.reference_weights(cfg, cell.family,
+                                     jax.devices()[:cell.chips])
     stream = TokenStream(t, cfg.vocab_size, seed)
     batches = [next(stream)["tokens"] for _ in range(t["check_steps"])]
-    ref = reference.readings(m, t, p0, batches)
+
+    def read(**kw):
+        return reference.readings(m, t, init, key, batches,
+                                  family=cell.family, **kw)
+
+    ref = read()
     out = {}
     for kind in kinds:
-        if kind == "control":
-            got = reference.readings(m, t, p0, batches, precision="fp8")
-        else:
-            got = reference.readings(m, t, p0, batches, fault=kind)
+        got = read(precision="fp8") if kind == "control" else read(fault=kind)
         out[kind] = compare.numbers(got, ref)
     return out
 

@@ -13,7 +13,9 @@ In order, in this one process:
 5. the window: whole steps on fresh batches made on the host while it
    runs, until ``--seconds`` have passed;
 6. the peak device memory, then the program's state is freed and the plain
-   reference runs the checked steps again; ``compare`` decides ``correct``.
+   reference, its state split over the cell's chips
+   (``reference.placement``), runs the checked steps again; ``compare``
+   decides ``correct``.
 
 With ``--trace 1`` the window runs under the JAX profiler and the cell's
 per-layer metrics are read from its trace instead of the end-to-end ones.
@@ -27,6 +29,7 @@ import shutil
 import sys
 import time
 from dataclasses import dataclass
+from functools import partial
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -103,6 +106,16 @@ def train_config(t: Dict[str, Any]) -> TrainConfig:
         steps=t["total_steps"], zero=t["zero"], remat=t["remat"])
 
 
+def reference_weights(cfg: ModelConfig, family, devices: List):
+    """``key -> weights`` jitted: the benchmark's weights as the program
+    holds them, made directly in the reference's placement over
+    ``devices``."""
+    make = partial(weights.make_params, cfg,
+                   init=getattr(family, "INIT", None))
+    shape = jax.eval_shape(make, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return jax.jit(make, out_shardings=reference.placement(devices, shape))
+
+
 def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
         t_start: float, devices: Optional[List] = None,
         peaks: Optional[Dict] = None, log=print) -> Dict[str, Any]:
@@ -129,10 +142,11 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
     mesh = make_plan_mesh(d, tp, devices)
     B, S = t["global_batch"], t["seq_len"]
     key = weights.seed_key(seed)
+    rules = getattr(cell.family, "INIT", None)
 
     # -- set-up: state, step, the checked steps --------------------------
     def make_state(k):
-        p = weights.make_params(cfg, k)
+        p = weights.make_params(cfg, k, rules)
         return {"params": p, "opt": init_opt_state(p),
                 "step": jnp.zeros((), jnp.int32)}
 
@@ -156,7 +170,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
         jax.tree.map(lambda x: x / (1.0 - b1), mom)))
     change_norms = jax.jit(lambda master, k: reference.layer_norms(
         jax.tree.map(lambda a, b: a - b.astype(jnp.float32), master,
-                     weights.make_params(cfg, k))))
+                     weights.make_params(cfg, k, rules))))
     checked, prog = [], {"loss": []}
     for i in range(t["check_steps"]):
         raw = next(stream)
@@ -224,10 +238,9 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
     # -- the check, with the program's state freed -------------------------
     del state, met, b
     gc.collect()
-    p0 = jax.jit(lambda k: weights.make_params(cfg, k),
-                 out_shardings=NamedSharding(mesh, P()))(key)
+    init = reference_weights(cfg, cell.family, devices)
     t_ref = time.perf_counter()
-    ref = reference.readings(m, t, p0, checked)
+    ref = reference.readings(m, t, init, key, checked, family=cell.family)
     log(f"reference: losses {ref['loss']}"
         f" ({time.perf_counter() - t_ref:.1f} s)")
     ok, checks = compare.verdict(compare.numbers(prog, ref), cell.limits)

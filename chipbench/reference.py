@@ -1,19 +1,18 @@
 """The plain reference of a training step, in float32 at the highest
 matmul precision, and the readings ``correct`` compares.
 
-It imports nothing of the program.  It follows the layer equations of the
-model configuration as the benchmark's configuration file states them:
+It imports nothing of the program.  The layer equations and the loss are
+the model family's, in ``references/<family>.py`` (found by name, see
+``spec.py``); what every family shares is here:
 
-- dense: token embedding; per layer RMSNorm, multi-head attention with
-  grouped KV heads, rotary positions (rotate-half) and a causal or sliding
-  window mask, output projection, residual; RMSNorm, GELU (tanh) MLP,
-  residual; final RMSNorm; logits from the tied embedding (or ``lm_head``).
-- loss: mean next-token cross-entropy over every position of every row.
 - AdamW with bias correction and decoupled weight decay, the learning
-  rate warmed up linearly and then decayed on a cosine to a tenth.
-
-Rows are taken in blocks so that the whole batch fits; the batch gradient
-is the mean of the blocks' gradients, as the program's micro-steps are.
+  rate warmed up linearly and then decayed on a cosine to a tenth;
+- rows taken in blocks so that the whole batch fits; the batch gradient
+  is the mean of the blocks' gradients, as the program's micro-steps are;
+- the placement of the reference's state over the cell's chips
+  (``placement``): each leaf split along one axis, each block of rows
+  split over the chips, so that a model no single chip holds can be
+  checked.  The step is plain ``jax.numpy``; XLA partitions it.
 
 ``precision="fp8"`` is the control: every matrix product's operands, and
 the cotangents the backward feeds to them, rounded to float8 (e4m3) with
@@ -22,18 +21,19 @@ take.
 """
 from __future__ import annotations
 
-import math
-from functools import partial
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 F32 = jnp.float32
 HIGHEST = jax.lax.Precision.HIGHEST
 FP8 = jnp.float8_e4m3fn
 FP8_MAX = 448.0
+#: the reference's one mesh axis: all of the cell's chips
+CHIPS = "chips"
 
 
 # ------------------------------------------------------------ precision --
@@ -52,89 +52,26 @@ def fp8(x):
 fp8.defvjp(lambda x: (_round_fp8(x), None), lambda _, g: (_round_fp8(g),))
 
 
-def make_ein(precision: str):
+def make_ein(precision: str, mesh: Optional[Mesh] = None):
     """``ein(spec, a, b)``: a float32 einsum at the highest precision, or,
-    for the control, the same with operands rounded to fp8."""
-    if precision == "f32":
-        return lambda spec, a, b: jnp.einsum(spec, a, b, precision=HIGHEST)
-    if precision == "fp8":
-        return lambda spec, a, b: jnp.einsum(spec, fp8(a), fp8(b),
-                                             precision=HIGHEST)
-    raise ValueError(f"unknown reference precision {precision!r}")
+    for the control, the same with operands rounded to fp8.  Over a
+    ``mesh`` of more than one chip, a product whose output starts with the
+    rows (``b``) keeps them split over the chips where their count
+    divides: the weights come to the rows, not the rows to the weights."""
+    if precision not in ("f32", "fp8"):
+        raise ValueError(f"unknown reference precision {precision!r}")
+    n = mesh.size if mesh is not None else 1
 
-
-# --------------------------------------------------------------- layers --
-
-def rms(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def rope(x, theta):
-    """x: (b, s, heads, d); rotate-half over positions 0..s-1."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=F32) / d))
-    ang = jnp.arange(x.shape[1], dtype=F32)[:, None] * inv
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def attention(m, ein, q, k, v):
-    """Causal (optionally windowed) softmax attention, one block of query
-    positions at a time.  q: (b, s, H, D); k, v: (b, s, K, D)."""
-    b, s, H, D = q.shape
-    G = H // k.shape[2]
-    k = jnp.repeat(k, G, axis=2)
-    v = jnp.repeat(v, G, axis=2)
-    window = m.get("sliding_window", 0)
-    qc = min(s, 1024)
-    outs = []
-    for i in range(0, s, qc):
-        sc = ein("bqhd,bkhd->bhqk", q[:, i:i + qc], k) / math.sqrt(D)
-        qp = i + jnp.arange(qc)[:, None]
-        kp = jnp.arange(s)[None, :]
-        ok = kp <= qp
-        if window:
-            ok &= kp > qp - window
-        sc = jnp.where(ok, sc, -jnp.inf)
-        outs.append(ein("bhqk,bkhd->bqhd", jax.nn.softmax(sc, -1), v))
-    return jnp.concatenate(outs, axis=1)
-
-
-def dense_layer(m, ein, x, p):
-    eps = m["norm_eps"]
-    h = rms(x, p["norm1"], eps)
-    a = p["mixer"]
-    q = rope(ein("bsd,dhk->bshk", h, a["wq"]), m["rope_theta"])
-    k = rope(ein("bsd,dhk->bshk", h, a["wk"]), m["rope_theta"])
-    v = ein("bsd,dhk->bshk", h, a["wv"])
-    x = x + ein("bshk,hkd->bsd", attention(m, ein, q, k, v), a["wo"])
-    h = rms(x, p["norm2"], eps)
-    f = p["ffn"]
-    u = ein("bsd,df->bsf", h, f["w1"])
-    if m["mlp_variant"] == "swiglu":
-        u = jax.nn.silu(u) * ein("bsd,df->bsf", h, f["w3"])
-    else:
-        u = jax.nn.gelu(u, approximate=True)
-    return x + ein("bsf,fd->bsd", u, f["w2"])
-
-
-def loss_fn(m, ein, params, tokens):
-    """Mean next-token cross-entropy of one block of rows."""
-    if m["family"] != "dense":
-        raise ValueError(f"no reference for model family {m['family']!r}")
-    x = params["embed"][tokens]
-
-    def body(x, p):
-        return dense_layer(m, ein, x, p["sub0"]), None
-
-    x, _ = jax.lax.scan(jax.checkpoint(body), x, params["blocks"])
-    x = rms(x, params["final_norm"], m["norm_eps"])
-    head = params.get("lm_head")
-    logits = (ein("bsd,dv->bsv", x, head) if head is not None
-              else ein("bsd,vd->bsv", x, params["embed"]))[:, :-1]
-    gold = jnp.take_along_axis(logits, tokens[:, 1:, None], -1)[..., 0]
-    return jnp.mean(jax.nn.logsumexp(logits, -1) - gold)
+    def ein(spec, a, b):
+        if precision == "fp8":
+            a, b = fp8(a), fp8(b)
+        out = jnp.einsum(spec, a, b, precision=HIGHEST)
+        if n > 1 and spec.split("->")[1].startswith("b") \
+                and out.shape[0] % n == 0:
+            out = jax.lax.with_sharding_constraint(
+                out, NamedSharding(mesh, P(CHIPS)))
+        return out
+    return ein
 
 
 # ------------------------------------------------------------- training --
@@ -177,22 +114,63 @@ def to_host(norms: Dict[str, jax.Array]) -> Dict[str, np.ndarray]:
     return {k: np.asarray(v) for k, v in norms.items()}
 
 
-def host_norms(tree) -> Dict[str, np.ndarray]:
-    return to_host(jax.jit(layer_norms)(tree))
+# ------------------------------------------------------------ placement --
+
+def placement(devices: Sequence, tree) -> Any:
+    """A ``NamedSharding`` for every leaf of ``tree`` (arrays or shapes)
+    over ``devices``: split along the leaf's largest axis that the chip
+    count divides (the first of equals), never the layer axis of a leaf
+    under ``blocks``; replicated where no axis qualifies."""
+    mesh = Mesh(np.asarray(devices), (CHIPS,))
+    n = len(devices)
+
+    def place(name, x):
+        lead = 1 if name.startswith("blocks/") else 0
+        axes = [a for a in range(lead, len(x.shape)) if x.shape[a] % n == 0]
+        if not axes:
+            return NamedSharding(mesh, P())
+        a = max(axes, key=lambda a: (x.shape[a], -a))
+        return NamedSharding(mesh, P(*[CHIPS if i == a else None
+                                       for i in range(len(x.shape))]))
+
+    flat, tdef = jax.tree.flatten(tree)
+    return tdef.unflatten([place(name, x)
+                           for name, x in zip(_names(tree), flat)])
 
 
-def make_step(m: Dict, t: Dict, precision: str, rows: int):
-    """``step(params, m1, v1, tokens, step) -> (loss, grads, params, m, v)``
-    jitted: the batch gradient in blocks of ``rows`` rows, then AdamW."""
-    ein = make_ein(precision)
+def to_f32(tree) -> Any:
+    """``tree`` in float32, each leaf where it was.  The cast is a program
+    of its own, from arrays that hold the values: fused into the program
+    that makes them, the compiler may skip their rounding to the
+    program's dtype (it did on a TPU v5e)."""
+    return jax.jit(lambda p: jax.tree.map(lambda x: x.astype(F32), p),
+                   out_shardings=jax.tree.map(lambda x: x.sharding, tree))(
+                       tree)
 
-    def grads_of(params, tokens):
-        blocks = tokens.reshape((-1, rows) + tokens.shape[1:])
-        vg = jax.value_and_grad(partial(loss_fn, m, ein))
+
+# ----------------------------------------------------------------- step --
+
+def make_step(m: Dict, t: Dict, precision: str, rows: int, loss: Callable,
+              shard: Any):
+    """``step(params, m1, v1, tokens, step) -> (loss, grad_norms, params,
+    m, v)`` jitted, with ``shard`` (``placement``) the parameters', the
+    moments' and the gradients' placement in and out: the batch gradient
+    in blocks of ``rows`` rows (``tokens``: ``(blocks, rows, seq)``), the
+    norms of its layers, then AdamW."""
+    mesh = jax.tree.leaves(shard)[0].mesh
+    ein = make_ein(precision, mesh)
+    one = NamedSharding(mesh, P())
+    # each block's rows split over the chips where their count divides
+    split_rows = NamedSharding(mesh, P(None, CHIPS) if rows % mesh.size == 0
+                               else P())
+
+    def grads_of(params, blocks):
+        vg = jax.value_and_grad(lambda p, b: loss(m, ein, p, b))
 
         def acc(carry, blk):
             l, g = vg(params, blk)
-            return jax.tree.map(jnp.add, carry, (l, g)), None
+            l, g = jax.tree.map(jnp.add, carry, (l, g))
+            return (l, jax.lax.with_sharding_constraint(g, shard)), None
 
         zero = (jnp.zeros((), F32), jax.tree.map(jnp.zeros_like, params))
         (l, g), _ = jax.lax.scan(acc, zero, blocks)
@@ -200,7 +178,7 @@ def make_step(m: Dict, t: Dict, precision: str, rows: int):
         return l / nb, jax.tree.map(lambda x: x / nb, g)
 
     def step(params, mom, vel, tokens, i):
-        loss, g = grads_of(params, tokens)
+        loss_, g = grads_of(params, tokens)
         lr = learning_rate(t, i)
         tt = i + 1.0
         c1, c2 = 1 - t["beta1"] ** tt, 1 - t["beta2"] ** tt
@@ -219,38 +197,50 @@ def make_step(m: Dict, t: Dict, precision: str, rows: int):
             new_m.append(mm)
             new_v.append(vv)
         un = tdef.unflatten
-        return loss, g, un(new_p), un(new_m), un(new_v)
+        return loss_, layer_norms(g), un(new_p), un(new_m), un(new_v)
 
-    return jax.jit(step, donate_argnums=(0, 1, 2))
+    return jax.jit(step, in_shardings=(shard, shard, shard, split_rows,
+                                       one),
+                   out_shardings=(one, one, shard, shard, shard),
+                   donate_argnums=(0, 1, 2))
 
 
-def readings(model: Dict, traffic: Dict, params0: Any,
-             batches: Sequence[np.ndarray], *, precision: str = "f32",
+def readings(model: Dict, traffic: Dict, init: Callable[[Any], Any],
+             key: Any, batches: Sequence[np.ndarray], *, family: Any,
+             precision: str = "f32",
              fault: Optional[str] = None) -> Dict[str, Any]:
-    """Run the reference through ``len(batches)`` steps from ``params0``.
+    """Run the reference through ``len(batches)`` steps from
+    ``init(key)``, the starting weights in the program's dtypes, already
+    in their ``placement``; ``family`` is the module of the model
+    family's equations.
 
     Returns the loss of each step, the norm of every layer of every leaf
     of the first step's gradient, and of the parameters' change over all
-    the steps.  ``fault="half_batch"`` leaves out the second half of every
-    batch (the mean taken over the rest), to read that fault."""
+    the steps: the starting weights are made again by ``init(key)`` for
+    it, not held through the steps.  ``fault="half_batch"`` leaves out the
+    second half of every batch (the mean taken over the rest), to read
+    that fault."""
     rows = int(traffic["reference_rows"])
-    start = jax.tree.map(lambda x: jnp.asarray(x, F32), params0)
-    params = jax.tree.map(jnp.copy, start)
-    mom = jax.tree.map(jnp.zeros_like, start)
-    vel = jax.tree.map(jnp.zeros_like, start)
+    params = to_f32(init(key))
+    shard = jax.tree.map(lambda x: x.sharding, params)
+    zeros = jax.jit(lambda p: jax.tree.map(jnp.zeros_like, p),
+                    out_shardings=shard)
+    mom, vel = zeros(params), zeros(params)
     with jax.default_matmul_precision("highest"):
-        step = make_step(model, traffic, precision, rows)
+        step = make_step(model, traffic, precision, rows, family.loss, shard)
         losses, grad_norms = [], None
         for i, toks in enumerate(batches):
             if fault == "half_batch":
                 toks = toks[:toks.shape[0] // 2]
-            loss, g, params, mom, vel = step(params, mom, vel,
-                                             jnp.asarray(toks), float(i))
+            toks = np.asarray(toks).reshape((-1, rows) + toks.shape[1:])
+            loss, gn, params, mom, vel = step(params, mom, vel, toks,
+                                              float(i))
             losses.append(float(loss))
             if i == 0:
-                grad_norms = host_norms(g)
-            del g
-        change = jax.tree.map(jnp.subtract, params, start)
-        change_norms = host_norms(change)
+                grad_norms = to_host(gn)
+        del mom, vel
+        change_norms = to_host(jax.jit(lambda p, s: layer_norms(
+            jax.tree.map(lambda a, b: a - b.astype(F32), p, s)))(
+                params, init(key)))
     return {"loss": losses, "grad_norms": grad_norms,
             "change_norms": change_norms}

@@ -5,6 +5,10 @@ traffic mix and a chip count.  Each part is a file of its own under this
 directory, found by name and never listed in code:
 
     configs/<config>.json    model sizes as run, source, cuts, deployment
+    references/<family>.py   the plain reference's layer equations and loss
+                             for the configuration's model family, and
+                             ``INIT``, initialisers of leaves the default
+                             rules do not cover (``weights.py``)
     traffic/<traffic>.json   sequence, batch, microbatch, ZeRO, mesh, data
     cells/<workload>.json    the limits of the numbers ``correct`` compares
     metrics/<metric>.py      one per-layer metric's reader
@@ -13,7 +17,15 @@ directory, found by name and never listed in code:
     peaks.json               published chip peaks keyed by ``device_kind``
 
 Adding a configuration, a traffic mix, a cell or a metric adds files and
-an entry in ``BENCHMARK.json``; nothing here changes.
+an entry in ``BENCHMARK.json``; nothing here changes.  A configuration of
+a family the benchmark has not met brings ``references/<family>.py`` with
+it, and nothing else changes: ``loss(model, ein, params, tokens)``, the
+mean loss of a block of rows in float32, and ``INIT`` where its leaves
+need one.  ``ein`` is the reference's matrix product
+(``reference.make_ein``); a product whose output holds the rows names
+them first, as ``b``, and the reference keeps them split over the cell's
+chips.  The placement of the reference's state over the chips is the
+same for every family.
 """
 from __future__ import annotations
 
@@ -49,6 +61,7 @@ class Cell:
     limits: Dict[str, float]
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    family: Any
     base: str = HERE
 
 
@@ -65,6 +78,7 @@ def load_cell(workload: str, *, root: str = ROOT, base: str = HERE) -> Cell:
     traffic = _load_json(os.path.join(base, "traffic",
                                       entry["traffic"] + ".json"))
     cell = _load_json(os.path.join(base, "cells", workload + ".json"))
+    family = reference_family(config["model"]["family"], base=base)
 
     def mine(metric):
         return workload in metric.get("workloads", [workload])
@@ -73,7 +87,7 @@ def load_cell(workload: str, *, root: str = ROOT, base: str = HERE) -> Cell:
                 traffic=traffic, limits=dict(cell["limits"]),
                 end_to_end=[m for m in bench["end_to_end"] if mine(m)],
                 per_layer=[m for m in bench["per_layer"] if mine(m)],
-                base=base)
+                family=family, base=base)
 
 
 def peaks_for(device_kind: str, *, base: str = HERE) -> Dict[str, Any]:
@@ -102,6 +116,13 @@ def metric_reader(name: str, *, base: str = HERE) -> Callable:
     """``read(run) -> float | None`` from ``metrics/<name>.py``."""
     return _load_module(os.path.join(base, "metrics", name + ".py"),
                         "chipbench_metric_" + name).read
+
+
+def reference_family(family: str, *, base: str = HERE):
+    """``references/<family>.py``: the family's ``loss`` for the plain
+    reference, and its ``INIT`` where it has one."""
+    return _load_module(os.path.join(base, "references", family + ".py"),
+                        "chipbench_reference_" + family)
 
 
 def _load_module(path: str, modname: str):

@@ -5,8 +5,9 @@ it; a sound run passes).
 
     JAX_PLATFORMS=cpu python -m pytest -q chipbench/tests
 
-The end-to-end cases run a two-layer dense model through the harness on the CPU (the chip check skipped), with limits of their own in
-``data/``: the cells' limits were set at full size on the chip.
+The end-to-end cases run a two-layer dense model through the harness on
+the CPU (the chip check skipped), with limits of their own in ``data/``
+(see ``tiny.py``): the cells' limits were set at full size on the chip.
 """
 from __future__ import annotations
 
@@ -30,6 +31,8 @@ import jax.numpy as jnp  # noqa: E402
 import compare  # noqa: E402
 import devtrace  # noqa: E402
 import spec  # noqa: E402
+import tiny  # noqa: E402
+import weights  # noqa: E402
 import work  # noqa: E402
 
 TINY = "tiny-dense.train"
@@ -115,18 +118,7 @@ def test_model_flops_by_hand():
 def tiny_root(tmp_path):
     """A checkout-like directory: the benchmark's files, the test's tiny
     cells added as files and entries, nothing else edited."""
-    base = tmp_path / "chipbench"
-    shutil.copytree(BENCH, base, ignore=shutil.ignore_patterns(
-        "__pycache__", "tests"))
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    cfg = TINY.split(".")[0]
-    shutil.copy(os.path.join(DATA, cfg + ".json"), base / "configs")
-    shutil.copy(os.path.join(DATA, TINY + ".json"), base / "cells")
-    shutil.copy(os.path.join(DATA, "tiny.json"), base / "traffic")
-    bench["workloads"].append({"name": TINY, "config": cfg,
-                               "traffic": "tiny", "chips": 1,
-                               "why": "CPU test"})
-    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    tiny.make_root(tmp_path)
     return tmp_path
 
 
@@ -142,6 +134,8 @@ def test_files_dropped_in_are_found_by_name(tiny_root):
     (tiny_root / "BENCHMARK.json").write_text(json.dumps(bench))
     cell = spec.load_cell(TINY, root=str(tiny_root), base=base)
     assert cell.config["model"]["num_layers"] == 2
+    assert cell.family.__file__ == os.path.join(base, "references",
+                                                "dense.py")
     assert cell.traffic["seq_len"] == 128
     assert "answer" in [m["name"] for m in cell.per_layer]
     assert spec.metric_reader("answer", base=base)(None) == 42.0
@@ -152,12 +146,85 @@ def test_files_dropped_in_are_found_by_name(tiny_root):
         spec.load_cell("no-such-cell", root=str(tiny_root), base=base)
 
 
+def _add_cell(root, name, model):
+    """A workload ``name`` of configuration ``model`` on the tiny traffic,
+    added as files and an entry."""
+    base = root / "chipbench"
+    cfg = name.split(".")[0]
+    (base / "configs" / (cfg + ".json")).write_text(json.dumps(
+        {"name": cfg, "model": model}))
+    shutil.copy(os.path.join(DATA, TINY + ".json"),
+                base / "cells" / (name + ".json"))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["workloads"].append({"name": name, "config": cfg,
+                               "traffic": "tiny", "chips": 1,
+                               "why": "CPU test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    return str(base)
+
+
+SSM_FAMILY = """
+import jax.numpy as jnp
+
+def _a_log(key, shape, dtype):
+    n = jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)
+    return jnp.log(jnp.broadcast_to(n, shape)).astype(dtype)
+
+INIT = {"A_log": _a_log, "D": "ones", "dt_bias": "zeros",
+        "conv_x_b": "zeros", "conv_bc_b": "zeros", "norm": "ones"}
+
+def loss(m, ein, params, tokens):
+    raise NotImplementedError
+"""
+
+
+def test_a_family_dropped_in_is_found_by_name(tiny_root):
+    """A configuration of a family the benchmark has not met needs only
+    ``references/<family>.py``; its ``INIT`` fills the vector leaves that
+    the default rules do not cover, and without it they are refused."""
+    import harness
+    from repro.configs.registry import smoke_config
+    cfg = smoke_config("mamba2-130m")
+    assert cfg.family == "ssm"
+    base = _add_cell(tiny_root, "tiny-ssm.train",
+                     {k: getattr(cfg, k) for k in cfg.__dataclass_fields__})
+    (tiny_root / "chipbench" / "references" / "ssm.py").write_text(
+        SSM_FAMILY)
+    cell = spec.load_cell("tiny-ssm.train", root=str(tiny_root), base=base)
+    assert cell.family.__file__ == os.path.join(base, "references",
+                                                "ssm.py")
+    assert harness.ModelConfig(**cell.config["model"]) == cfg
+    key = weights.seed_key(3000000019)
+    mixer = weights.make_params(cfg, key, cell.family.INIT)[
+        "blocks"]["sub0"]["mixer"]
+    n = cfg.n_ssm_heads
+    assert (mixer["A_log"] == jnp.log(jnp.arange(1.0, n + 1))).all()
+    assert (mixer["D"] == 1).all() and (mixer["norm"] == 1).all()
+    for name in ("dt_bias", "conv_x_b", "conv_bc_b"):
+        assert (mixer[name] == 0).all(), name
+    assert mixer["in_zx"].std() > 0
+    with pytest.raises(ValueError, match="no initialiser for vector leaf"):
+        weights.make_params(cfg, key)
+
+
+def test_a_family_with_no_file_fails_when_the_cell_is_loaded(tiny_root):
+    model = json.load(open(os.path.join(DATA, "tiny-dense.json")))["model"]
+    base = _add_cell(tiny_root, "tiny-moe.train", dict(model, family="moe"))
+    with pytest.raises(spec.SpecError, match=os.path.join(
+            base, "references", "moe.py")):
+        spec.load_cell("tiny-moe.train", root=str(tiny_root), base=base)
+
+
 def test_every_cell_of_the_benchmark_has_its_files():
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     for w in bench["workloads"]:
         cell = spec.load_cell(w["name"])
         assert set(cell.limits) == {"loss_gap", "grad_gap", "update_gap"} \
             or set(cell.limits) == {"grad_gap", "update_gap"}
+        family = cell.config["model"]["family"]
+        assert cell.family.__file__ == os.path.join(BENCH, "references",
+                                                    family + ".py")
+        assert callable(cell.family.loss)
         for m in cell.per_layer:
             spec.metric_reader(m["name"])
 
@@ -199,54 +266,19 @@ def test_command_prints_no_result_without_a_tpu(tmp_path):
 
 # ---------------------------------------------------------------- check --
 
-def _run(root, name, seed=7):
-    import harness
-    cell = spec.load_cell(name, root=str(root), base=str(root / "chipbench"))
-    return harness.run(cell, seed, 0.5, False, t_start=0.0,
-                       devices=jax.devices(), peaks=spec.peaks_for(
-                           "TPU v5 lite"), log=lambda s: None)
-
-
 def test_sound_run_is_correct(tiny_root):
-    out = _run(tiny_root, TINY)
+    out = tiny.run(tiny_root, TINY)
     assert out["correct"], out["checks"]
     assert list(out)[-1] == "checks"
     assert set(out["metrics"]) == {"train_tokens_per_s", "peak_hbm_gib",
                                    "setup_s"}
 
 
-def _broken(kind):
-    """``build_train_step`` with the timed step broken underneath."""
-    import harness
-    real_build = harness.build_train_step
-
-    def build(cfg, tc, mesh, batch, seq, *, jit=False):
-        real, n_micro = real_build(cfg, tc, mesh, batch, seq, jit=False)
-        if kind == "stale_state":
-            def step(state, b):
-                return state, real(state, b)[1]
-        elif kind == "half_batch":
-            def step(state, b):
-                half = jax.tree.map(lambda x: jnp.concatenate(
-                    [x[:batch // 2]] * 2), b)
-                return real(state, half)
-        elif kind == "altered_update":
-            def step(state, b):
-                new, met = real(state, b)
-                leaf = new["opt"]["master"]["blocks"]["sub0"]["mixer"]
-                old = state["opt"]["master"]["blocks"]["sub0"]["mixer"]["wq"]
-                leaf["wq"] = leaf["wq"].at[0].set(2 * leaf["wq"][0] - old[0])
-                return new, met
-        return jax.jit(step), n_micro
-    return build
-
-
-@pytest.mark.parametrize("kind", ["stale_state", "half_batch",
-                                  "altered_update"])
+@pytest.mark.parametrize("kind", tiny.BROKEN)
 def test_broken_step_is_not_correct(tiny_root, monkeypatch, kind):
     import harness
-    monkeypatch.setattr(harness, "build_train_step", _broken(kind))
-    out = _run(tiny_root, TINY)
+    monkeypatch.setattr(harness, "build_train_step", tiny.broken(kind))
+    out = tiny.run(tiny_root, TINY)
     assert not out["correct"], out["checks"]
 
 

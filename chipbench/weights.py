@@ -6,6 +6,9 @@ filled here by the usual rule for its role, from a key folded from the
 seed and the leaf's path, so the plain reference can make the very same
 weights without taking anything the program made:
 
+- a leaf whose last name the model family's ``INIT`` lists
+  (``references/<family>.py``): its rule, ``"ones"``, ``"zeros"`` or a
+  function of the key, the shape and the dtype;
 - embedding: truncated normal, std 0.02 (GPT-2);
 - norm scales: ones;
 - every other leaf is a matrix: truncated normal with std
@@ -19,7 +22,7 @@ from __future__ import annotations
 
 import math
 import zlib
-from typing import Any, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +30,9 @@ import numpy as np
 
 ONES = ("norm1", "norm2", "final_norm")
 OUT_PROJ = ("wo", "w2")
+
+#: a family's initialiser of a leaf, by the leaf's last name
+Rule = Union[str, Callable[[jax.Array, Tuple[int, ...], Any], jax.Array]]
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -41,10 +47,17 @@ def path_name(path) -> str:
 
 
 def _leaf(name: str, key, shape: Tuple[int, ...], dtype, stacked: bool,
-          num_layers: int):
+          num_layers: int, init: Dict[str, Rule]):
     last = name.rsplit("/", 1)[-1]
     per_layer = shape[1:] if stacked else shape
     f32 = jnp.float32
+    rule = init.get(last)
+    if rule == "ones":
+        return jnp.ones(shape, dtype)
+    if rule == "zeros":
+        return jnp.zeros(shape, dtype)
+    if rule is not None:
+        return rule(key, shape, dtype)
     if last in ONES:
         return jnp.ones(shape, dtype)
     if last == "embed":
@@ -60,8 +73,9 @@ def _leaf(name: str, key, shape: Tuple[int, ...], dtype, stacked: bool,
             * std).astype(dtype)
 
 
-def make_params(cfg, key) -> Any:
-    """The parameter tree of ``cfg`` filled from ``key`` (traceable)."""
+def make_params(cfg, key, init: Optional[Dict[str, Rule]] = None) -> Any:
+    """The parameter tree of ``cfg`` filled from ``key`` (traceable), with
+    the family's rules ``init`` before the defaults."""
     from repro.models import init_params
     shapes = jax.eval_shape(lambda k: init_params(cfg, k),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
@@ -70,6 +84,6 @@ def make_params(cfg, key) -> Any:
         name = path_name(path)
         k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
         return _leaf(name, k, tuple(s.shape), s.dtype,
-                     name.startswith("blocks/"), cfg.num_layers)
+                     name.startswith("blocks/"), cfg.num_layers, init or {})
 
     return jax.tree_util.tree_map_with_path(fill, shapes)
