@@ -1,4 +1,7 @@
-"""StarCoder2-3B [arXiv:2402.19173] — dense, GQA(kv=2), RoPE, sliding window 4096."""
+"""StarCoder2-3B [arXiv:2402.19173] — dense, GQA(kv=2), RoPE, sliding window 4096.
+
+``rope_theta`` and the tied embedding as the published ``config.json``
+(https://huggingface.co/bigcode/starcoder2-3b)."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -12,6 +15,7 @@ CONFIG = ModelConfig(
     vocab_size=49152,
     attention="gqa",
     sliding_window=4096,        # native SWA [arXiv:2402.19173]
-    rope_theta=1e5,
+    rope_theta=999999.4420358813,
     mlp_variant="gelu",
+    tie_embeddings=True,
 )

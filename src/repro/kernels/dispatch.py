@@ -19,7 +19,8 @@ The choice can be forced either way with the ``REPRO_KERNELS`` env var
 Each public op (``attention``, ``ssd``, ``adam_update_leaf``,
 ``flash_decode``, ``mla_flash_decode``) runs inside a ``jax.named_scope``
 of its own name, so every device op it lowers to (a kernel's backward
-included) carries that name in a profiler trace (``obs.device``).
+included) carries that name in a profiler trace (``obs.device``); the
+attention kernel's backward carries ``attention_bwd`` inside it.
 
 Resolution is memoized — after the first call per ``(op, backend,
 override)`` the lookup amortizes to a single dict hit, guarded by the
@@ -203,10 +204,12 @@ def _flash_attention_fwd(q, k, v, *static):
 
 
 def _flash_attention_bwd(causal, window, softmax_scale, res, g):
-    _, vjp = jax.vjp(functools.partial(_attention_ref, causal=causal,
-                                       window=window,
-                                       softmax_scale=softmax_scale), *res)
-    return vjp(g)
+    with jax.named_scope(obs_device.ATTENTION_BWD):
+        _, vjp = jax.vjp(functools.partial(_attention_ref, causal=causal,
+                                           window=window,
+                                           softmax_scale=softmax_scale),
+                         *res)
+        return vjp(g)
 
 
 _flash_attention_vjp.defvjp(_flash_attention_fwd, _flash_attention_bwd)

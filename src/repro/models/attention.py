@@ -195,7 +195,10 @@ def _chunked_attention_scan(q: jax.Array, k: jax.Array, v: jax.Array, *,
         acc0 = jnp.zeros((b, qc, K, G, D), jnp.float32)
         m0 = jnp.full((b, K, G, qc), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, K, G, qc), jnp.float32)
-        (acc, m, l), _ = jax.lax.scan(kv_body, (acc0, m0, l0),
+        # the backward recomputes each pair's scores from the carry: saved,
+        # they would hold (nq x band) score blocks of (qc x kc) at once
+        (acc, m, l), _ = jax.lax.scan(jax.checkpoint(kv_body),
+                                      (acc0, m0, l0),
                                       jnp.arange(band))
         out_i = acc / jnp.maximum(jnp.moveaxis(l, -1, 1)[..., None], 1e-30)
         return None, out_i.astype(q_i.dtype)

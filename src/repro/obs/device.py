@@ -9,7 +9,14 @@ Three kinds, all read back from one ``jax.profiler`` trace:
   backward, and ``rematted_computation`` inside it the recomputed forward.
   ``MODEL`` covers the forward and loss, ``GRAD_ACCUM`` the f32 gradient
   accumulation (backward work), ``OPTIMIZER`` the whole update; each public
-  op of ``kernels.dispatch`` runs under a scope of its own name;
+  op of ``kernels.dispatch`` runs under a scope of its own name.  Nested
+  scopes name the exchanges between chips and a kernel's backward:
+  ``GRAD_SYNC`` (in ``GRAD_ACCUM``) the summed gradient taken to the
+  optimizer's ZeRO-1 shards (XLA reduces it over the data replicas in the
+  backward already, in all-reduces it leaves unnamed); ``PARAM_GATHER``
+  (in ``OPTIMIZER``) the new parameters cast and taken back to their own
+  sharding, the all-gather over the data replicas; ``ATTENTION_BWD`` (in
+  ``ATTENTION``) the backward of the attention kernel's ``custom_vjp``;
 * host spans (``span``): ``jax.profiler.TraceAnnotation`` under ``repro/``,
   with their keyword arguments as stats; next to free with no profiler
   running.  ``repro/train_step`` marks one dispatch of the step
@@ -29,8 +36,11 @@ from jax import monitoring
 MODEL = "model"
 OPTIMIZER = "optimizer"
 GRAD_ACCUM = "grad_accum"
+GRAD_SYNC = "grad_sync"
+PARAM_GATHER = "param_gather"
 # ``kernels.dispatch``: each public op runs under a scope of its own name
 ATTENTION = "attention"
+ATTENTION_BWD = "attention_bwd"
 SSD = "ssd"
 ADAM_UPDATE_LEAF = "adam_update_leaf"
 FLASH_DECODE = "flash_decode"

@@ -12,6 +12,12 @@ logical dim names; the active context (set by the train/serve step builders)
 resolves them to mesh axes for the current (cfg, mesh), dropping axes that
 do not divide the dim (jit requires exact tiling).  With no context active
 this is a no-op, so model code runs unchanged outside pjit.
+
+Code traced for one shard inside ``per_shard``'s map sees no context
+either: the mesh is manual there and takes no constraint on its global
+axes.  The rule is read from the mesh JAX traces under, so it also holds
+where the map's transpose traces a ``custom_vjp``'s backward, after the
+forward's wrapper has returned.
 """
 from __future__ import annotations
 
@@ -59,11 +65,20 @@ def activation_sharding(mesh: Mesh, cfg):
         _CTX.ctx = prev
 
 
+def _active():
+    """The active ``(mesh, resolved)`` pair, or None: none was set, or the
+    code is being traced for one shard of a ``shard_map``."""
+    ctx = getattr(_CTX, "ctx", None)
+    if ctx is None or jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    return ctx
+
+
 def logical_spec(shape, *dims) -> P:
     """The PartitionSpec the active context gives an array of ``shape``
     with logical ``dims``; axes that do not divide their dim are dropped.
     Fully replicated when no context is active."""
-    ctx = getattr(_CTX, "ctx", None)
+    ctx = _active()
     if ctx is None:
         return P()
     mesh, resolved = ctx
@@ -83,7 +98,7 @@ def logical_spec(shape, *dims) -> P:
 
 
 def constrain(x: jax.Array, *dims) -> jax.Array:
-    ctx = getattr(_CTX, "ctx", None)
+    ctx = _active()
     if ctx is None:
         return x
     return jax.lax.with_sharding_constraint(
@@ -98,7 +113,7 @@ def per_shard(fn, in_specs, out_specs):
     opaque custom call.  ``fn`` must give the right answer on each shard
     alone.  With no context active, or a one-device mesh, ``fn`` itself
     is returned."""
-    ctx = getattr(_CTX, "ctx", None)
+    ctx = _active()
     if ctx is None or ctx[0].size == 1:
         return fn
     return jax.shard_map(fn, mesh=ctx[0], in_specs=in_specs,

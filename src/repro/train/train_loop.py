@@ -68,7 +68,8 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh,
     double-buffered, halving the optimizer-state working set.  It is wrapped
     in ``obs.device.TracedStep``: each call opens the ``repro/train_step``
     host span.  Either way the step's ops carry the ``obs.device`` scopes
-    (``model``, ``grad_accum``, ``optimizer``) in their names.  jit=False
+    (``model``, ``grad_accum``, ``optimizer``, and ``grad_sync`` and
+    ``param_gather`` inside the last two) in their names.  jit=False
     (default) returns the traceable step for callers that lower it with
     explicit shardings (launch.dryrun) or wrap it themselves.
     """
@@ -114,8 +115,11 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh,
             with jax.named_scope(obs_device.GRAD_ACCUM):
                 g = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
                                  g_acc, g)
-                g = jax.tree_util.tree_map(
-                    lambda x, s: constrain(x, s), g, opt_spec)
+                # the sum to the optimizer's ZeRO-1 shards; the compiler
+                # reduces it over the data replicas in the backward already
+                with jax.named_scope(obs_device.GRAD_SYNC):
+                    g = jax.tree_util.tree_map(
+                        lambda x, s: constrain(x, s), g, opt_spec)
             return (g, loss_acc + ce), None
 
         with jax.named_scope(obs_device.GRAD_ACCUM):
@@ -125,7 +129,7 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh,
         (g_sum, loss_sum), _ = jax.lax.scan(accum, (g0, 0.0), micros)
         with jax.named_scope(obs_device.OPTIMIZER):
             grads = jax.tree.map(lambda g: g / n_micro, g_sum)
-            new_params, new_opt, gnorm = adam_update(
+            _, new_opt, gnorm = adam_update(
                 tc, params, state["opt"], grads, state["step"], opt_spec)
             # hand the params back in the layout they came in: left free,
             # the compiler shards them like the ZeRO master copy they are
@@ -133,7 +137,12 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, mesh: Mesh,
             # next step
             p_spec = sh.param_specs(cfg, params, mesh,
                                     zero_data=tc.zero >= 3)
-            new_params = jax.tree.map(constrain, new_params, p_spec)
+            # the all-gather of the new parameters over the data replicas;
+            # the compiler names it after the cast, so the cast is in here
+            with jax.named_scope(obs_device.PARAM_GATHER):
+                new_params = jax.tree.map(
+                    lambda mp, p, s: constrain(mp.astype(p.dtype), s),
+                    new_opt["master"], params, p_spec)
         new_state = {"params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         metrics = {"loss": loss_sum / n_micro, "grad_norm": gnorm}

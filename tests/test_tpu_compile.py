@@ -202,3 +202,64 @@ def test_train_step_2x2_mesh(topo, tpu_backend):
     compiled = jax.jit(step, in_shardings=(s_sh, b_sh),
                        donate_argnums=(0,)).lower(state, batch).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+COLLECTIVE = re.compile(r"(?:\}|\)|\]) ((?:all-reduce|all-gather|reduce-scatter"
+                        r"|collective-permute|all-to-all)(?:-start)?)\(")
+
+
+def test_train_step_2x2_mesh_starcoder2_3b_15l(topo, tpu_backend):
+    """The whole step of ``starcoder2-3b-15l.train.s8192.2x2`` (its
+    configuration and traffic files) on a 2x2 (data x model) mesh: 15
+    layers at StarCoder2-3B's widths, 8,192 tokens, one row a data replica
+    a micro-step.  The attention backward runs per shard, the state and
+    the step's temporaries fit a 16 GB chip, and the exchanges carry the
+    step's names: the new parameters' all-gathers ``param_gather``.  The
+    compiler reduces the gradient over the data replicas inside the
+    backward, per layer, in all-reduces it leaves without a name, so
+    ``grad_sync`` (the ZeRO-1 slice of the summed gradient) names no
+    collective and is left in the lowered program only."""
+    import json
+
+    from jax.sharding import AxisType, Mesh
+
+    from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
+    from repro.launch.inputs import train_inputs
+    from repro.train import build_train_step
+
+    bench = os.path.join(os.path.dirname(__file__), "..", "chipbench")
+    with open(os.path.join(bench, "configs", "starcoder2-3b-15l.json")) as f:
+        cfg = ModelConfig(**json.load(f)["model"])
+    with open(os.path.join(bench, "traffic", "train.s8192.2x2.json")) as f:
+        t = json.load(f)
+    B, S = t["global_batch"], t["seq_len"]
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(t["mesh"]),
+                ("data", "model"), axis_types=(AxisType.Auto,) * 2)
+    tc = TrainConfig(global_batch=B, seq_len=S, microbatch=t["microbatch"],
+                     zero=t["zero"], remat=t["remat"])
+    (state, batch), (s_sh, b_sh) = train_inputs(
+        cfg, ShapeConfig("train", S, B, "train"), mesh, tc)
+    step, n_micro = build_train_step(cfg, tc, mesh, B, S)
+    assert n_micro == 2
+    lowered = jax.jit(step, in_shardings=(s_sh, b_sh),
+                      donate_argnums=(0,)).lower(state, batch)
+    assert "grad_sync" in lowered.as_text(debug_info=True)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9, mem
+    text = compiled.as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert any("/attention_bwd/" in n for n in names)
+    assert re.search(r"%flash_attention_fwd(\.\d+)? = .*custom_call_target="
+                     r'"tpu_custom_call"', text)
+    exchanges = []
+    for line in text.splitlines():
+        m = COLLECTIVE.search(line.partition(" = ")[2])
+        if m:
+            n = re.search(r'op_name="([^"]*)"', line)
+            exchanges.append((m.group(1), n.group(1) if n else ""))
+    gathers = [n for op, n in exchanges if "/param_gather/" in n]
+    assert gathers and all(op == "all-gather" for op, n in exchanges
+                           if "/param_gather/" in n), exchanges
+    assert not any("grad_sync" in n for _, n in exchanges), exchanges
+    assert any(op == "all-reduce" and not n for op, n in exchanges)
