@@ -20,7 +20,7 @@ Each public op (``attention``, ``ssd``, ``adam_update_leaf``,
 ``flash_decode``, ``mla_flash_decode``) runs inside a ``jax.named_scope``
 of its own name, so every device op it lowers to (a kernel's backward
 included) carries that name in a profiler trace (``obs.device``); the
-attention kernel's backward carries ``attention_bwd`` inside it.
+attention kernels' backward carries ``attention_bwd`` inside it.
 
 Resolution is memoized — after the first call per ``(op, backend,
 override)`` the lookup amortizes to a single dict hit, guarded by the
@@ -200,16 +200,24 @@ def _flash_attention_vjp(q, k, v, causal, window, softmax_scale):
 
 
 def _flash_attention_fwd(q, k, v, *static):
-    return _flash_attention_vjp(q, k, v, *static), (q, k, v)
+    o = _flash_attention_vjp(q, k, v, *static)
+    return o, (q, k, v, o)
 
 
 def _flash_attention_bwd(causal, window, softmax_scale, res, g):
+    from repro.kernels.flash_attention.flash_attention import (
+        flash_attention_bwd, flash_attention_bwd_tiling)
+    q, k, v, o = res
     with jax.named_scope(obs_device.ATTENTION_BWD):
-        _, vjp = jax.vjp(functools.partial(_attention_ref, causal=causal,
-                                           window=window,
-                                           softmax_scale=softmax_scale),
-                         *res)
-        return vjp(g)
+        if flash_attention_bwd_tiling(q.shape[1], k.shape[1], q.shape[3],
+                                      q.dtype) is None:
+            # no block pair fits the kernels' VMEM: the chunked ref's VJP
+            _, vjp = jax.vjp(functools.partial(
+                _attention_ref, causal=causal, window=window,
+                softmax_scale=softmax_scale), q, k, v)
+            return vjp(g)
+        return flash_attention_bwd(q, k, v, o, g, causal=causal,
+                                   window=window, softmax_scale=softmax_scale)
 
 
 _flash_attention_vjp.defvjp(_flash_attention_fwd, _flash_attention_bwd)
@@ -230,9 +238,12 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
               softmax_scale: Optional[float] = None):
     """q: (b, sq, H, D); k, v: (b, sk, K, D), H = K*G.  Returns (b, sq, H, D).
 
-    The Pallas kernel is forward-only.  Its ``custom_vjp`` saves q, k, v
-    and takes the backward as the VJP of the chunked ref
-    (``models.attention.chunked_attention``), recomputed from them."""
+    On the Pallas path a ``custom_vjp`` saves q, k, v and the output, and
+    its backward is the Pallas flash backward (``flash_attention_bwd``: a
+    dq call that also rebuilds each row's log-sum-exp, then a dK/dV call),
+    tiled by ``flash_attention_bwd_tiling``; only a shape that no block
+    pair fits falls back to the VJP of the chunked ref
+    (``models.attention.chunked_attention``)."""
     with jax.named_scope(obs_device.ATTENTION):
         return resolve("attention")[1](q, k, v, causal=causal,
                                        window=window,

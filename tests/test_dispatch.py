@@ -264,12 +264,19 @@ def test_dispatch_pallas_attention_matches_ref(b, sq, sk, H, K, D, causal,
                                atol=tol, rtol=tol)
 
 
-# ------------------- custom_vjp: Pallas forward, chunked-ref backward ---
+# ---------------- custom_vjp: Pallas forward, Pallas backward kernels ---
 
+# blocks come from the shape (flash_attention_bwd_tiling): 1,100 tokens
+# take 128-row blocks, 1,536 and 2,560 take 512
 @pytest.mark.parametrize("b,s,H,K,D,window", [
     (2, 128, 4, 2, 64, 0),                     # GQA causal
     (1, 128, 4, 4, 32, 48),                    # MHA + sliding window
     (1, 256, 8, 2, 64, 0),                     # GQA, two 128-row blocks
+    (1, 2560, 12, 1, 32, 1200),                # GQA 12:1, window of 3 blocks
+    (1, 1100, 4, 2, 32, 0),                    # padded tail block
+    (1, 256, 4, 2, 128, 0),                    # D 128
+    (1, 256, 4, 4, 192, 0),                    # D 192 (MLA's width)
+    (1, 1536, 4, 2, 64, 300),                  # window inside one block
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_dispatch_pallas_attention_grad_matches_ref(b, s, H, K, D, window,
@@ -294,6 +301,29 @@ def test_dispatch_pallas_attention_grad_matches_ref(b, s, H, K, D, window,
         np.testing.assert_allclose(np.asarray(a, np.float32) / scale,
                                    np.asarray(b_, np.float32) / scale,
                                    atol=tol, rtol=tol)
+
+
+def test_dispatch_pallas_attention_grad_falls_back_without_tiling():
+    """A head too wide for any backward block pair: the custom_vjp takes
+    the chunked ref's VJP, and the gradients still match the ref's."""
+    from repro.kernels.flash_attention.flash_attention import (
+        flash_attention_bwd_tiling)
+    b, s, H, D = 1, 16, 1, 16384
+    assert flash_attention_bwd_tiling(s, s, D, jnp.float32) is None
+    ks = jax.random.split(jax.random.PRNGKey(12), 3)
+    q, k, v = (jax.random.normal(key, (b, s, H, D), jnp.float32)
+               for key in ks)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v, causal=True) ** 2)
+    with dispatch.force("pallas"):
+        got = jax.grad(loss(dispatch.attention), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(attention_ref), argnums=(0, 1, 2))(q, k, v)
+    for a, w in zip(got, want):
+        scale = max(float(jnp.max(jnp.abs(w))), 1.0)
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(w) / scale,
+                                   atol=2e-5, rtol=2e-5)
 
 
 def test_dispatch_pallas_ssd_grad_matches_ref():

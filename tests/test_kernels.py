@@ -5,10 +5,12 @@ import numpy as np
 import pytest
 
 from repro.kernels.adam_update import adam_ref, adam_update_fused
-from repro.kernels.flash_attention import attention_ref, flash_attention
+from repro.kernels.flash_attention import (attention_ref, flash_attention,
+                                           flash_attention_bwd)
 from repro.kernels.flash_attention.flash_attention import (
-    VMEM_BUDGET, block_live, feasible_tilings, flash_attention_tiling,
-    kv_span, vmem_bytes)
+    VMEM_BUDGET, block_live, bwd_vmem_bytes, feasible_bwd_tilings,
+    feasible_tilings, flash_attention_bwd_tiling, flash_attention_tiling,
+    kv_span, q_span, vmem_bytes)
 from repro.kernels.ssd_scan import ssd_ref, ssd_scan
 
 
@@ -72,13 +74,29 @@ def _live_steps(sq, sk, bq, bk, causal, window):
     return n
 
 
+def _live_steps_by_kv(sq, sk, bq, bk, causal, window):
+    """The same block pairs counted from the KV side, as the dK/dV kernel
+    walks them: each KV block's live q blocks are the whole of its
+    ``q_span``."""
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    n = 0
+    for ik in range(nk):
+        live = [iq for iq in range(nq)
+                if block_live(iq * bq, ik * bk, bq, bk, causal, window)]
+        first, last = q_span(ik * bk, bq, bk, nq, causal, window)
+        if live:
+            assert live == list(range(int(first), int(last) + 1)), (ik, live)
+        n += len(live)
+    return n
+
+
 def _band_blocks(sq, sk, bq, bk, causal, window):
     """Block pairs holding a (q, k) pair inside the band, counted over the
     whole (padded) score matrix."""
     sq_p, sk_p = -(-sq // bq) * bq, -(-sk // bk) * bk
     qp = np.arange(sq_p)[:, None]
     kp = np.arange(sk_p)[None, :]
-    ok = kp < sk
+    ok = np.broadcast_to(kp < sk, (sq_p, sk_p))
     if causal:
         ok = ok & (kp <= qp)
     if window:
@@ -107,6 +125,86 @@ def test_flash_attention_tiling(sq, sk, D, window):
             _band_blocks(sq, sk, tq, tk, True, window), (tq, tk)
     if (sq, D) == (1024, 64):                       # gpt2-350m's cell
         assert (bq, bk) == (1024, 1024)
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk", [
+    (1024, 1024, 128, 128), (1024, 1024, 256, 128), (1024, 1024, 128, 512),
+    (1000, 1000, 128, 256), (300, 700, 128, 128), (8192, 8192, 1024, 512),
+])
+@pytest.mark.parametrize("causal,window", [
+    (True, 0), (True, 1), (True, 100), (True, 128), (True, 300),
+    (True, 4096), (False, 0), (False, 200),
+])
+def test_q_span_matches_block_live(sq, sk, bq, bk, causal, window):
+    """Every (q block, KV block) pair: the KV side's ``q_span`` keeps
+    exactly the pairs ``block_live`` keeps, as ``kv_span`` does from the
+    q side, and both count the band's blocks."""
+    n = _live_steps_by_kv(sq, sk, bq, bk, causal, window)
+    assert n == _live_steps(sq, sk, bq, bk, causal, window)
+    assert n == _band_blocks(sq, sk, bq, bk, causal, window)
+
+
+# (sq, sk, D, window) of the widths that train through the backward:
+# gpt2-350m, llama3.2-3b, MLA, and 1k to 8k tokens with and without
+# starcoder2's window
+@pytest.mark.parametrize("sq,sk,D,window", [
+    (1024, 1024, 64, 0), (1024, 1024, 128, 0), (1024, 1024, 192, 0),
+    (2048, 2048, 128, 0), (4096, 4096, 64, 0), (4096, 4096, 192, 2048),
+    (8192, 8192, 128, 4096), (8192, 8192, 192, 0), (1100, 1100, 64, 0),
+])
+def test_flash_attention_bwd_tiling(sq, sk, D, window):
+    tiling = flash_attention_bwd_tiling(sq, sk, D, jnp.bfloat16)
+    assert tiling is not None                  # no fallback to the ref
+    bq, bk = tiling
+    assert bq % 128 == 0 and bk % 128 == 0, tiling
+    assert bwd_vmem_bytes(bq, bk, D, jnp.bfloat16) <= VMEM_BUDGET
+    tilings = feasible_bwd_tilings(sq, sk, D, jnp.bfloat16)
+    assert tiling in tilings
+    assert max(a * b for a, b in tilings) == bq * bk
+    for tq, tk in tilings:
+        assert bwd_vmem_bytes(tq, tk, D, jnp.bfloat16) <= VMEM_BUDGET
+        assert _live_steps_by_kv(sq, sk, tq, tk, True, window) == \
+            _band_blocks(sq, sk, tq, tk, True, window), (tq, tk)
+    if (sq, D) in ((1024, 64), (8192, 128)):    # the benchmark's cells
+        assert tiling == (1024, 1024)
+
+
+def test_flash_attention_bwd_tiling_none_when_nothing_fits():
+    """A head too wide for any block pair gives no tiling: the dispatch
+    layer then takes the chunked ref's VJP."""
+    assert flash_attention_bwd_tiling(1024, 1024, 8192, jnp.bfloat16) is None
+
+
+# blocks of the backward's own tiling (None: the tiling's own pick)
+@pytest.mark.parametrize("b,sq,sk,H,K,D,causal,window,bq,bk", [
+    (1, 512, 512, 2, 2, 64, True, 0, 128, 256),     # bq < bk
+    (1, 512, 512, 2, 1, 64, True, 0, 256, 128),     # bq > bk, MQA
+    (1, 1024, 1024, 4, 1, 64, True, 300, 256, 256),  # GQA, window skips
+    (1, 300, 300, 2, 2, 32, True, 0, 128, 128),     # padded tail block
+    (2, 64, 192, 4, 1, 64, False, 0, None, None),   # cross-length
+    (1, 200, 200, 2, 1, 32, True, 0, None, None),   # one padded block
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_bwd_tiled(b, sq, sk, H, K, D, causal, window, bq,
+                                   bk, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (b, sq, H, D), dtype)
+    k = jax.random.normal(ks[1], (b, sk, K, D), dtype)
+    v = jax.random.normal(ks[2], (b, sk, K, D), dtype)
+    do = jax.random.normal(ks[3], (b, sq, H, D), dtype)
+    o = flash_attention(q, k, v, causal=causal, window=window,
+                        interpret=True)
+    got = flash_attention_bwd(q, k, v, o, do, causal=causal, window=window,
+                              block_q=bq, block_k=bk, interpret=True)
+    _, vjp = jax.vjp(lambda q, k, v: attention_ref(
+        q, k, v, causal=causal, window=window), q, k, v)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    for a, w in zip(got, vjp(do)):
+        assert a.dtype == w.dtype and a.shape == w.shape
+        scale = max(float(jnp.max(jnp.abs(w.astype(jnp.float32)))), 1.0)
+        np.testing.assert_allclose(np.asarray(a, np.float32) / scale,
+                                   np.asarray(w, np.float32) / scale,
+                                   atol=tol, rtol=tol)
 
 
 @pytest.mark.parametrize("b,s,h,p,n,chunk", [

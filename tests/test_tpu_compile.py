@@ -14,6 +14,7 @@ imports this file.
 """
 from __future__ import annotations
 
+import json
 import os
 import re
 
@@ -67,14 +68,16 @@ def tpu_backend(monkeypatch):
     dispatch.clear_caches()
 
 
-def _compile(kernel, fn, *shapes):
-    """Compile ``fn`` and check that the Pallas call named ``kernel`` is
-    in the program: the name is its HLO instruction's."""
+def _compile(kernels, fn, *shapes):
+    """Compile ``fn`` and check that the Pallas call named ``kernels`` (or
+    each of several) is in the program: the name is its HLO
+    instruction's."""
     compiled = jax.jit(fn).lower(*shapes).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    assert re.search(r"%" + kernel + r"(\.\d+)? = .*custom_call_target="
-                     r'"tpu_custom_call"', text), kernel
+    for kernel in (kernels,) if isinstance(kernels, str) else kernels:
+        assert re.search(r"%" + kernel + r"(\.\d+)? = .*custom_call_target="
+                         r'"tpu_custom_call"', text), kernel
     return compiled
 
 
@@ -91,13 +94,14 @@ ATTN_WIDTHS = {
     "deepseek-v2-mla": ((1, 1024, 128, 192), (1, 1024, 128, 192), 0),
     "starcoder2-3b": ((1, 8192, 24, 128), (1, 8192, 2, 128), 4096),
 }
-# the grad at starcoder2-3b's width runs on one chip of a model axis of 2
-# (12 of 24 heads, 1 of 2 KV heads): with all its heads the chunked ref's
-# backward needs more than the chip's HBM
+# the grad at every forward width, and at starcoder2-3b's on one chip of a
+# model axis of 2 (12 of 24 heads, 1 of 2 KV heads), as its four-chip cell
+# runs it
 ATTN_GRAD_WIDTHS = {
     **ATTN_WIDTHS,
-    "starcoder2-3b": ((1, 8192, 12, 128), (1, 8192, 1, 128), 4096),
+    "starcoder2-3b-tp2": ((1, 8192, 12, 128), (1, 8192, 1, 128), 4096),
 }
+BWD_KERNELS = ("flash_attention_bwd_dq", "flash_attention_bwd_dkv")
 
 
 @pytest.mark.parametrize("arch", sorted(ATTN_WIDTHS))
@@ -119,8 +123,52 @@ def test_flash_attention_grad(one_chip, tpu_backend, arch):
     def loss(q, k, v):
         return dispatch.attention(q, k, v, causal=True,
                                   window=window).astype(F32).sum()
-    _compile("flash_attention_fwd",
+    # the Pallas backward, not the chunked ref's VJP, at every width
+    _compile(("flash_attention_fwd", *BWD_KERNELS),
              jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+
+def _selector():
+    path = os.path.join(os.path.dirname(__file__), "..", "chipbench",
+                        "events", "flash_attention.json")
+    with open(path) as f:
+        return re.compile(json.load(f)["match"]["name"])
+
+
+@pytest.mark.parametrize("arch", ["gpt2-350m", "starcoder2-3b", "starcoder2-3b-tp2"])
+def test_flash_attention_selector_picks_forward_only(one_chip, tpu_backend,
+                                                     arch):
+    """In the compiled value and grad, the benchmark's attention selector
+    (``chipbench/events/flash_attention.json``, read, not edited) picks
+    the forward kernel's instructions and no backward call, and every
+    backward call carries ``attention_bwd`` in its ``op_name``."""
+    qs, ks, window = ATTN_GRAD_WIDTHS[arch]
+    q, kv = _sds(one_chip, qs), _sds(one_chip, ks)
+
+    def loss(q, k, v):
+        return dispatch.attention(q, k, v, causal=True,
+                                  window=window).astype(F32).sum()
+    from jax._src.lib import xla_client
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
+    # instructions as a trace's events name them: operands with shapes
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    text = "\n".join(m.to_string(opts) for m in
+                     compiled.runtime_executable().hlo_modules())
+    calls = [line.strip() for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    picked = [c for c in calls if _selector().search(c)]
+    assert picked and all(c.startswith("%flash_attention_fwd")
+                          for c in picked), picked
+    assert len(picked) == sum(c.startswith("%flash_attention_fwd")
+                              for c in calls)
+    for kernel in BWD_KERNELS:
+        bwd = [c for c in calls if c.startswith("%" + kernel)]
+        assert bwd, kernel
+        for c in bwd:
+            name = re.search(r'op_name="([^"]*)"', c)
+            assert name and "/attention_bwd/" in name.group(1), c
 
 
 # llama3.2-3b decode: the serve smoke's cache (128 prompt + 16 generated,
